@@ -21,8 +21,7 @@ Claims split by kind, mirroring ``results/bench_baseline/tolerances.json``:
 
 * *structural* (timing-insensitive; what CI's perf-smoke gates): skip
   vs dense numerics agreement, the live-block fraction actually
-  shrinking, pallas-triton registration, the engine resolving every
-  query. Identical between ``--smoke`` and full runs — the simulator
+  shrinking, the engine resolving every query. Identical between ``--smoke`` and full runs — the simulator
   sections use the same seeded traces in both modes.
 * *timing* (full runs only; CI skips via ``bench_diff --skip-timing``):
   the >= 2x prefill gate. ``--smoke`` drops timing iterations to 1 and
@@ -182,21 +181,16 @@ def run(smoke: bool = False) -> dict:
     engine = _engine_overhead(warmup, iters)
     cluster = _cluster_throughput(warmup, iters)
 
-    triton_kernels = sum(
-        1 for name in DISPATCHER.kernels()
-        if "pallas-triton" in DISPATCHER.registered_tiers(name))
     longest = f"S{PREFILL_LENGTHS[-1]}"
     payload = {
         "prefill": prefill, "decode": decode, "engine": engine,
         "cluster": cluster,
-        "tiers": {"pallas_triton_kernels": float(triton_kernels)},
         "claims": {
             # structural: stable across hosts/modes, gated in CI smoke
             "prefill_skip_matches_dense": prefill_agree,
             "decode_skip_matches_dense": decode_agree,
             "prefill_skips_dead_blocks":
                 prefill[longest]["live_frac"] <= 0.75,
-            "pallas_triton_tier_registered": triton_kernels >= 3,
             "engine_resolves_all_queries":
                 engine["resolved_frac"] >= 1.0,
         },

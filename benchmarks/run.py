@@ -73,6 +73,8 @@ def main(argv=None) -> int:
                          "compact artifact CI uploads)")
     args = ap.parse_args(argv)
 
+    from repro import compat
+    compat.enable_compile_cache()
     names = select(args.only, args.skip)
     if not names:
         print(f"--only {args.only} --skip {args.skip} matches no benchmark "

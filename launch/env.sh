@@ -32,22 +32,11 @@ unset _lib
 # quiet the TF/XLA C++ banner noise that otherwise floods bench logs
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
 
-# step markers bound each engine dispatch in profiler traces, so
-# per-query overhead in bench_hotpath attributes to the right step.
-# TPU hosts only: the CPU/GPU XLA flag parser hard-aborts on unknown
-# flags, so this must never leak onto a non-TPU machine.
-if [ -e /dev/accel0 ] || [ -n "${TPU_NAME:-}" ]; then
-  case " ${XLA_FLAGS:-} " in
-    *xla_step_marker_location*) ;;
-    *) export XLA_FLAGS="--xla_step_marker_location=1${XLA_FLAGS:+ $XLA_FLAGS}" ;;
-  esac
-fi
-
 # dtype pinning: the kernels accumulate in f32 by construction; x64
 # mode would silently double every buffer and halve throughput
 export JAX_ENABLE_X64="${JAX_ENABLE_X64:-0}"
 export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}"
 
-# kernel tier: leave REPRO_KERNEL_TIER unset to probe
-# (tpu -> pallas-triton -> interpret -> ref); export it to pin a tier.
+# kernel tier: leave REPRO_KERNEL_TIER unset to follow the platform
+# (tpu on a TPU backend; interpret/ref on the CPU); export it to pin one.
 unset _REPRO_ROOT

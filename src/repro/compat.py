@@ -1,78 +1,51 @@
-"""Version-adaptive JAX/Pallas compatibility shim.
+"""Backend capability, compile counting and kernel-tier resolution.
 
-Every JAX API whose surface has moved across the versions this repo
-supports (0.4.3x .. 0.5+) is feature-probed here ONCE, at import, and
-exposed behind a stable name. Nothing outside this module may touch
-``pltpu.TPUCompilerParams`` / ``pltpu.CompilerParams``,
-``jax.sharding.AxisType``, or the ``AbstractMesh`` constructor
-directly — the probe results below are the single source of truth.
+The repository runs on one installation (jax/jaxlib 0.9.0, libtpu
+0.0.34): JAX and Pallas surfaces are called directly wherever they are
+used. What lives here is what the program has to *decide* about the
+host it runs on:
 
-Probed surfaces
----------------
-* Pallas TPU compiler params:  ``TPUCompilerParams`` (<= 0.4.x) vs
-  ``CompilerParams`` (newer releases renamed it).
-* ``jax.sharding.AbstractMesh``: pair signature
-  ``AbstractMesh(((name, size), ...))`` (0.4.37) vs the split
-  ``AbstractMesh(shape, axes)`` form of newer releases.
-* ``jax.make_mesh``: the ``axis_types=`` kwarg and the
-  ``jax.sharding.AxisType`` enum only exist on newer releases.
-* Backend capability: whether a TPU backend is attached, and whether
-  Pallas interpret mode actually executes on this host (probed by
-  running a one-element kernel, not by guessing from the version).
-* Compiled-path probes (serving/executor.py): a process-wide XLA
-  compile counter riding ``jax.monitoring`` backend-compile events
-  (:func:`compile_events` / :class:`CompileCounter` — the proof that
-  SubNetAct actuation never recompiles), AOT compilation through the
-  ``jit(...).lower(...).compile()`` stages API (:func:`aot_compile`,
-  falling back to ``None`` so callers warm eagerly), and whether
-  buffer donation is actually honored on this backend
-  (:func:`donation_works` — a real donated round trip, not a platform
-  guess).
+* **Compile counting** — a process-wide XLA compile counter riding
+  ``jax.monitoring`` backend-compile events (:func:`compile_events` /
+  :class:`CompileCounter`): the proof that SubNetAct actuation never
+  recompiles (serving/executor.py).
+* **Donation** — whether buffer donation is honored on this backend
+  (:func:`donation_works`, a real donated round trip).
+* **Compile cache** — :func:`enable_compile_cache`, called by entry
+  points (never at import) to keep JAX's persistent compilation cache
+  in one fixed place.
 
 Kernel dispatch tiers
 ---------------------
-The Pallas kernels run through a four-tier fallback chain, resolved
-once per process (see :mod:`repro.kernels.dispatch`):
+The Pallas kernels run at one of three tiers (see
+:mod:`repro.kernels.dispatch`):
 
-    ``tpu``           — compiled Pallas kernels on a real TPU backend
-    ``pallas-triton`` — backend-agnostic Pallas kernels compiled via
-                        the Triton lowering on a GPU backend
-    ``interpret``     — the TPU kernels under the Pallas interpreter
-                        (CPU CI: validates kernel numerics without a TPU)
-    ``ref``           — the pure-jnp oracles in :mod:`repro.kernels.ref`
+    ``tpu``       — compiled Pallas kernels on a TPU backend
+    ``interpret`` — the same kernels under the Pallas interpreter
+                    (CPU tests: validates kernel numerics without a TPU)
+    ``ref``       — the pure-jnp oracles in :mod:`repro.kernels.ref`
 
-Override with ``REPRO_KERNEL_TIER=tpu|pallas-triton|interpret|ref`` or
-:func:`set_kernel_tier`.
+The process tier follows the platform: ``tpu`` on a TPU backend,
+``interpret`` elsewhere. There is no probed fallback: a tier the host
+cannot run is an error. Override with ``REPRO_KERNEL_TIER=tpu|interpret|
+ref`` or :func:`set_kernel_tier`.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional, Sequence, Tuple
+from pathlib import Path
+from typing import Optional
 
 import jax
 
 __all__ = [
-    "JAX_VERSION",
-    "HAS_PALLAS",
-    "HAS_PALLAS_TPU",
-    "HAS_PALLAS_TRITON",
     "KERNEL_TIERS",
     "backend",
     "is_tpu_backend",
-    "is_gpu_backend",
-    "triton_compiler_params_kwargs",
-    "tpu_compiler_params",
-    "compiler_params_kwargs",
-    "make_abstract_mesh",
-    "make_mesh",
-    "cost_analysis",
     "compile_events",
     "CompileCounter",
-    "aot_compile",
     "donation_works",
-    "pallas_interpret_works",
-    "cpu_subprocess_env",
-    "host_devices_env",
+    "enable_compile_cache",
     "tier_available",
     "kernel_tier",
     "explicit_kernel_tier",
@@ -81,206 +54,47 @@ __all__ = [
 ]
 
 
-def _version_tuple(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split(".")[:3]:
-        digits = "".join(ch for ch in p if ch.isdigit())
-        parts.append(int(digits) if digits else 0)
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _version_tuple(jax.__version__)
-
-
 # --------------------------------------------------------------------------
-# Pallas import probes
-# --------------------------------------------------------------------------
-
-try:
-    from jax.experimental import pallas as _pl  # noqa: F401
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover - pallas always present in-tree
-    _pl = None
-    HAS_PALLAS = False
-
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    HAS_PALLAS_TPU = True
-except Exception:  # pragma: no cover
-    _pltpu = None
-    HAS_PALLAS_TPU = False
-
-try:
-    from jax.experimental.pallas import triton as _pltriton
-    HAS_PALLAS_TRITON = True
-except Exception:  # pragma: no cover - absent on some builds
-    _pltriton = None
-    HAS_PALLAS_TRITON = False
-
-# The compiler-params dataclass was renamed TPUCompilerParams ->
-# CompilerParams across Pallas releases; accept either.
-_COMPILER_PARAMS_CLS = None
-if HAS_PALLAS_TPU:
-    for _name in ("TPUCompilerParams", "CompilerParams"):
-        _COMPILER_PARAMS_CLS = getattr(_pltpu, _name, None)
-        if _COMPILER_PARAMS_CLS is not None:
-            break
-
-
-def tpu_compiler_params(**kwargs):
-    """Instance of whichever Pallas-TPU compiler-params class exists.
-
-    Returns None when no class is available (or none of the requested
-    fields are supported) — callers splat :func:`compiler_params_kwargs`
-    into ``pl.pallas_call`` so the argument vanishes entirely in that
-    case.
-    """
-    if _COMPILER_PARAMS_CLS is None:
-        return None
-    fields = getattr(_COMPILER_PARAMS_CLS, "__dataclass_fields__", None)
-    if fields is not None:
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-        if not kwargs:
-            return None
-    try:
-        return _COMPILER_PARAMS_CLS(**kwargs)
-    except TypeError:
-        return None
-
-
-def compiler_params_kwargs(**kwargs) -> dict:
-    """``{"compiler_params": ...}`` for pallas_call, or ``{}``."""
-    params = tpu_compiler_params(**kwargs)
-    return {"compiler_params": params} if params is not None else {}
-
-
-def triton_compiler_params_kwargs(**kwargs) -> dict:
-    """``{"compiler_params": TritonCompilerParams(...)}`` or ``{}``.
-
-    Unknown fields are dropped (the dataclass gained/lost fields across
-    releases); with no Triton module or no surviving fields the kwarg
-    vanishes entirely, which is also the right thing under interpret
-    mode where compiler params are ignored anyway.
-    """
-    if _pltriton is None:
-        return {}
-    cls = getattr(_pltriton, "TritonCompilerParams", None) or \
-        getattr(_pltriton, "CompilerParams", None)
-    if cls is None:
-        return {}
-    fields = getattr(cls, "__dataclass_fields__", None)
-    if fields is not None:
-        kwargs = {k: v for k, v in kwargs.items() if k in fields}
-        if not kwargs:
-            return {}
-    try:
-        return {"compiler_params": cls(**kwargs)}
-    except TypeError:
-        return {}
-
-
-# --------------------------------------------------------------------------
-# Mesh construction
-# --------------------------------------------------------------------------
-
-
-def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across both constructor signatures.
-
-    jax 0.4.37 takes one ``((name, size), ...)`` pair tuple; newer
-    releases take ``(axis_sizes, axis_names)`` split positionally.
-    """
-    from jax.sharding import AbstractMesh
-    pairs = tuple(zip(tuple(axes), tuple(shape)))
-    try:
-        return AbstractMesh(pairs)
-    except (TypeError, ValueError):
-        return AbstractMesh(tuple(shape), tuple(axes))
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
-    """``jax.make_mesh`` with auto axis types where the API supports it.
-
-    ``axis_types=`` (and ``jax.sharding.AxisType``) only exist on newer
-    releases; on 0.4.37 the plain call already yields Auto axes.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes,
-                axis_types=(axis_type.Auto,) * len(axes), **kwargs)
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def cost_analysis(compiled) -> dict:
-    """Flat dict from ``compiled.cost_analysis()`` across versions.
-
-    jax 0.4.3x returns a one-element list of dicts (per executable);
-    newer releases return the dict directly; either may be empty/None.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
-
-
-# --------------------------------------------------------------------------
-# Compiled-path probes: compile counting, AOT compilation, donation
+# Compile counting and donation
 # --------------------------------------------------------------------------
 
 _compile_events = 0
-_compile_listener_ok: Optional[bool] = None
+_compile_listener_installed = False
 
 
-def _note_compile_event(*args, **kwargs) -> None:
-    """jax.monitoring duration listener. The signature has grown extra
-    kwargs across releases, so accept anything and read the event name
-    positionally; only backend (XLA) compilations are counted — jaxpr
-    tracing and MLIR lowering re-run cheaply on cache hits too."""
+def _note_compile_event(event: str, *args, **kwargs) -> None:
+    """jax.monitoring duration listener. Only backend (XLA) compilations
+    are counted — jaxpr tracing and MLIR lowering re-run cheaply on
+    cache hits too."""
     global _compile_events
-    event = args[0] if args else kwargs.get("event", "")
-    if isinstance(event, str) and "backend_compile" in event:
+    if "backend_compile" in event:
         _compile_events += 1
 
 
-def _install_compile_listener() -> bool:
-    global _compile_listener_ok
-    if _compile_listener_ok is None:
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                _note_compile_event)
-            _compile_listener_ok = True
-        except Exception:
-            _compile_listener_ok = False
-    return _compile_listener_ok
+def _install_compile_listener() -> None:
+    global _compile_listener_installed
+    if not _compile_listener_installed:
+        jax.monitoring.register_event_duration_secs_listener(
+            _note_compile_event)
+        _compile_listener_installed = True
 
 
-def compile_events() -> Optional[int]:
-    """Monotone count of XLA backend compilations in this process, or
-    ``None`` when the ``jax.monitoring`` surface is unavailable.
+def compile_events() -> int:
+    """Monotone count of XLA backend compilations in this process.
 
     This is the SubNetAct enforcement probe: serving code asserts the
     count does NOT move across subnet actuations (control tuples are
     traced data, never part of the jit cache key)."""
-    return _compile_events if _install_compile_listener() else None
+    _install_compile_listener()
+    return _compile_events
 
 
 class CompileCounter:
     """``with CompileCounter() as cc: ...; cc.count`` — XLA backend
-    compilations during the block. ``cc.available`` is False (and
-    ``count`` 0) when the monitoring probe is missing; callers gating
-    hard guarantees should skip rather than trust a blind counter."""
+    compilations during the block."""
 
     def __init__(self):
-        self.available = _install_compile_listener()
+        _install_compile_listener()
         self._start = 0
         self.count = 0
 
@@ -289,154 +103,75 @@ class CompileCounter:
         return self
 
     def __exit__(self, *exc) -> None:
-        if self.available:
-            self.count = _compile_events - self._start
-
-
-def aot_compile(jitted, *args, **kwargs):
-    """``jitted.lower(*args, **kwargs).compile()`` behind a probe.
-
-    Returns the compiled executable — ready to call with concrete
-    arrays matching the lowered shapes — or ``None`` when the AOT
-    stages API is missing or lowering fails on this release; callers
-    fall back to eager first-call warmup."""
-    lower = getattr(jitted, "lower", None)
-    if lower is None:
-        return None
-    try:
-        return lower(*args, **kwargs).compile()
-    except Exception:
-        return None
+        self.count = _compile_events - self._start
 
 
 _donation_probe: Optional[bool] = None
 
 
 def donation_works() -> bool:
-    """Probe (once) whether buffer donation is honored on this backend.
-
-    An actual donated round trip checking the input buffer was
-    consumed — not a platform guess: CPU donation flipped from ignored
-    (with a warning) to honored across jaxlib releases, and the only
-    trustworthy signal is the input array turning deleted."""
+    """Probe (once) whether buffer donation is honored on this backend:
+    an actual donated round trip, checking that the input buffer was
+    consumed."""
     global _donation_probe
-    if _donation_probe is not None:
-        return _donation_probe
-    try:
+    if _donation_probe is None:
         import jax.numpy as jnp
         f = jax.jit(lambda x: x + 1, donate_argnums=(0,))
         x = jnp.ones((8,), jnp.float32)
         jax.block_until_ready(f(x))
-        deleted = getattr(x, "is_deleted", None)
-        _donation_probe = bool(deleted()) if callable(deleted) else False
-    except Exception:
-        _donation_probe = False
+        _donation_probe = bool(x.is_deleted())
     return _donation_probe
 
 
 # --------------------------------------------------------------------------
-# Backend capability + kernel tier resolution
+# Persistent compilation cache
 # --------------------------------------------------------------------------
 
-KERNEL_TIERS = ("tpu", "pallas-triton", "interpret", "ref")
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, inside the checkout (and in .gitignore): the cache key includes
+# the path, so a directory that moves between runs would never hit
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    it stays in charge. Otherwise the cache goes to ``.jax_cache/`` at
+    the root of the checkout. Entry points call this; importing the
+    package never does."""
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return str(DEFAULT_CACHE_DIR)
+
+
+# --------------------------------------------------------------------------
+# Backend + kernel tier resolution
+# --------------------------------------------------------------------------
+
+KERNEL_TIERS = ("tpu", "interpret", "ref")
 _TIER_ENV = "REPRO_KERNEL_TIER"
 _tier_cache: Optional[str] = None
 _explicit_tier: Optional[str] = None
-_interpret_probe: Optional[bool] = None
 
 
 def backend() -> str:
     return jax.default_backend()
 
 
-def cpu_subprocess_env(**extra) -> dict:
-    """Minimal env for spawning a CPU-pinned python subprocess.
-
-    Tests that force ``--xla_force_host_platform_device_count`` are
-    CPU-only by construction; without ``JAX_PLATFORMS=cpu`` a host with
-    a TPU wheel installed (but no TPU attached) stalls for minutes in
-    libtpu's GCP-metadata retry loop before falling back.
-    """
-    env = {
-        "PYTHONPATH": "src",
-        "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-        "HOME": os.environ.get("HOME", "/root"),
-        "JAX_PLATFORMS": "cpu",
-    }
-    env.update(extra)
-    return env
-
-
-def host_devices_env(n: int, **extra) -> dict:
-    """``cpu_subprocess_env`` plus fake-device pinning: with ``n > 0``
-    the child sees ``XLA_FLAGS=--xla_force_host_platform_device_count=n``
-    (appended to any inherited XLA_FLAGS), so its *first* jax import
-    gets an n-device CPU host — the HomebrewNLP-Jax/olmax idiom that
-    lets sharded multi-process tests run on CPU CI without TPUs. Used
-    by serving/ipc.py to spawn replica worker processes."""
-    env = cpu_subprocess_env(**extra)
-    if n and int(n) > 0:
-        flags = env.get("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
-        pin = f"--xla_force_host_platform_device_count={int(n)}"
-        env["XLA_FLAGS"] = f"{flags} {pin}".strip()
-    return env
-
-
 def is_tpu_backend() -> bool:
     return backend() == "tpu"
 
 
-def is_gpu_backend() -> bool:
-    # jax.default_backend() says "gpu" on most releases but the platform
-    # name underneath is cuda/rocm; accept any of them.
-    return backend() in ("gpu", "cuda", "rocm")
-
-
-def pallas_interpret_works() -> bool:
-    """Probe (once) whether Pallas interpret mode runs on this host.
-
-    An actual one-element kernel execution, not a version check: the
-    interpreter's own API surface has shifted between releases, and the
-    only trustworthy signal is a successful round trip.
-    """
-    global _interpret_probe
-    if _interpret_probe is not None:
-        return _interpret_probe
-    if not HAS_PALLAS:
-        _interpret_probe = False
-        return False
-    try:
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-
-        def _copy(x_ref, o_ref):
-            o_ref[...] = x_ref[...]
-
-        # The first resolution may happen while tracing a model step;
-        # the probe must execute eagerly regardless, or the bool()
-        # below sees a tracer and misreports the tier as unavailable.
-        with jax.ensure_compile_time_eval():
-            x = jnp.ones((8, 128), jnp.float32)
-            y = pl.pallas_call(
-                _copy, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-                interpret=True)(x)
-            _interpret_probe = bool((y == x).all())
-    except Exception:
-        _interpret_probe = False
-    return _interpret_probe
-
-
 def tier_available(tier: str) -> bool:
-    """Whether a dispatch tier can actually execute on this host."""
-    if tier == "tpu":
-        return HAS_PALLAS_TPU and is_tpu_backend()
-    if tier == "pallas-triton":
-        return HAS_PALLAS_TRITON and is_gpu_backend()
-    if tier == "interpret":
-        # the interpret-tier kernels use pltpu grid specs, so the plain
-        # pallas probe alone is not sufficient
-        return HAS_PALLAS_TPU and pallas_interpret_works()
-    return tier == "ref"
+    """Whether a dispatch tier can execute on this host: compiled Pallas
+    TPU kernels need a TPU backend; the interpreter and the oracles run
+    anywhere."""
+    if tier not in KERNEL_TIERS:
+        return False
+    return tier != "tpu" or is_tpu_backend()
 
 
 def _env_tier() -> Optional[str]:
@@ -453,29 +188,21 @@ def _env_tier() -> Optional[str]:
     return env
 
 
-def _resolve_tier() -> str:
-    env = _env_tier()
-    if env is not None:
-        return env
-    for tier in KERNEL_TIERS:
-        if tier_available(tier):
-            return tier
-    return "ref"
-
-
 def kernel_tier() -> str:
-    """The process-wide kernel dispatch tier, resolved once."""
+    """The process-wide kernel dispatch tier, resolved once: an explicit
+    override, else ``tpu`` on a TPU backend and ``interpret``
+    elsewhere."""
     global _tier_cache
     if _tier_cache is None:
-        _tier_cache = _resolve_tier()
+        _tier_cache = _env_tier() or ("tpu" if is_tpu_backend()
+                                      else "interpret")
     return _tier_cache
 
 
 def explicit_kernel_tier() -> Optional[str]:
     """The tier the operator *asked* for (env var or set_kernel_tier),
-    or None when the process tier is purely probed. Model hot paths use
-    this to honor a forced tier while defaulting interpret-capable CPU
-    hosts to the fast pure-JAX path."""
+    or None when the process tier follows the platform. Model hot paths
+    use this to honor a forced tier."""
     if _explicit_tier is not None:
         return _explicit_tier
     return _env_tier()

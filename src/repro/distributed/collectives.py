@@ -20,7 +20,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -76,9 +75,9 @@ def seq_sharded_decode(mesh: Mesh, q, k_cache, v_cache, index,
 
     spec_q = P(None, None, None, None)
     spec_kv = P(None, None, ax, None)
-    out = shard_map(body, mesh=mesh,
-                    in_specs=(spec_q, spec_kv, spec_kv),
-                    out_specs=spec_q, check_rep=False)(q, k_cache, v_cache)
+    out = jax.shard_map(body, mesh=mesh,
+                        in_specs=(spec_q, spec_kv, spec_kv),
+                        out_specs=spec_q, check_vma=False)(q, k_cache, v_cache)
     return out
 
 
@@ -105,5 +104,5 @@ def ring_allgather(mesh: Mesh, x, axis: str):
         buf, _ = lax.fori_loop(0, n - 1, step, (buf0, x_))
         return buf
 
-    return shard_map(body, mesh=mesh, in_specs=P(axis),
-                     out_specs=P(None, axis), check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                         out_specs=P(None, axis), check_vma=False)(x)

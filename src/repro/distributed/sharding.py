@@ -56,11 +56,11 @@ class ShardingPlan:
     def abstract(cls, shape: Tuple[int, ...], axes: Tuple[str, ...],
                  cfg: ArchConfig, **kwargs) -> "ShardingPlan":
         """Plan over a device-free AbstractMesh (rule tests, planning
-        tools on hosts without the target topology). Constructed via
-        the compat shim — the AbstractMesh constructor signature moved
-        across JAX versions."""
-        from repro import compat
-        return cls(compat.make_abstract_mesh(shape, axes), cfg, **kwargs)
+        tools on hosts without the target topology)."""
+        from jax.sharding import AbstractMesh, AxisType
+        mesh = AbstractMesh(tuple(shape), tuple(axes),
+                            axis_types=(AxisType.Auto,) * len(axes))
+        return cls(mesh, cfg, **kwargs)
 
     # ---- axis helpers -------------------------------------------------
     @property

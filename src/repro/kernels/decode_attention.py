@@ -17,7 +17,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -110,7 +109,8 @@ def decode_attention(q, k_cache, v_cache, index, *, window: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, d), v_cache.dtype),
         interpret=interpret,
-        **compat.compiler_params_kwargs(
+        name="decode_attention",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(idx, q3, k_cache, v_cache)
     return out.reshape(B, Hq, 1, d)

@@ -1,27 +1,23 @@
-"""Capability-probing dispatcher for the Pallas kernels.
+"""Tier dispatcher for the Pallas kernels.
 
 One registry maps each kernel name to its implementations per tier:
 
-    ``tpu``           — compiled Pallas kernel (TPU backend attached)
-    ``pallas-triton`` — backend-agnostic Pallas kernel lowered through
-                        Triton (GPU backend attached)
-    ``interpret``     — the TPU Pallas kernel under the interpreter
-                        (CPU hosts: validates kernel numerics, slowly)
-    ``ref``           — the pure-jnp oracle from :mod:`repro.kernels.ref`
+    ``tpu``       — compiled Pallas kernel (TPU backend attached)
+    ``interpret`` — the TPU Pallas kernel under the interpreter
+                    (CPU hosts: validates kernel numerics, slowly)
+    ``ref``       — the pure-jnp oracle from :mod:`repro.kernels.ref`
 
 The process tier is resolved once by :func:`repro.compat.kernel_tier`
-(``tpu -> pallas-triton -> interpret -> ref`` fallback chain,
-overridable via the ``REPRO_KERNEL_TIER`` env var or
-:func:`repro.compat.set_kernel_tier`).
-A kernel that lacks an implementation at the process tier falls through
-to the next tier down the chain, so registering a new backend or kernel
-variant is a one-file change: implement + register, and every call site
-above (models, serving, launch) picks it up.
+(``tpu`` on a TPU backend, ``interpret`` elsewhere; overridable via the
+``REPRO_KERNEL_TIER`` env var or :func:`repro.compat.set_kernel_tier`).
+Every kernel registers every tier; resolving a tier a kernel lacks
+raises instead of substituting another.
 
 Model hot paths use :func:`model_tier` instead of the raw process tier:
-an explicit override is honored verbatim, but a *probed* ``interpret``
-tier degrades to ``ref`` there — the interpreter is a numerics
-validation vehicle, orders of magnitude too slow for model-sized calls.
+an explicit override is honored verbatim; otherwise a TPU backend runs
+the ``tpu`` kernels and any other backend the ``ref``/XLA path — the
+interpreter is a numerics validation vehicle, orders of magnitude too
+slow for model-sized calls.
 """
 from __future__ import annotations
 
@@ -31,7 +27,7 @@ from repro import compat
 
 
 class KernelDispatcher:
-    """Name -> {tier -> impl} registry with chain-fallback resolution."""
+    """Name -> {tier -> impl} registry."""
 
     def __init__(self):
         self._impls: Dict[str, Dict[str, Callable]] = {}
@@ -53,24 +49,18 @@ class KernelDispatcher:
     def resolve(self, name: str,
                 tier: Optional[str] = None) -> Tuple[str, Callable]:
         """(tier, impl) for ``name``. ``tier=None`` uses the process
-        tier, falling down the chain past unregistered tiers."""
+        tier; a tier the kernel does not register raises."""
         try:
             impls = self._impls[name]
         except KeyError:
             raise KeyError(f"no kernel named {name!r}; "
                            f"registered: {self.kernels()}") from None
-        if tier is not None:
-            if tier not in impls:
-                raise KeyError(
-                    f"kernel {name!r} has no {tier!r} tier; "
-                    f"registered tiers: {self.registered_tiers(name)}")
-            return tier, impls[tier]
-        start = compat.KERNEL_TIERS.index(compat.kernel_tier())
-        for cand in compat.KERNEL_TIERS[start:]:
-            if cand in impls:
-                return cand, impls[cand]
-        raise KeyError(f"kernel {name!r} has no tier at or below "
-                       f"{compat.kernel_tier()!r}")
+        tier = tier or compat.kernel_tier()
+        if tier not in impls:
+            raise KeyError(
+                f"kernel {name!r} has no {tier!r} tier; "
+                f"registered tiers: {self.registered_tiers(name)}")
+        return tier, impls[tier]
 
     def call(self, name: str, *args, tier: Optional[str] = None, **kwargs):
         _, fn = self.resolve(name, tier)
@@ -99,15 +89,11 @@ def coerce_tier(tier: Optional[str], interpret: Optional[bool]) -> Optional[str]
 def model_tier() -> str:
     """Dispatch tier for model hot paths (forward/decode under jit).
 
-    Explicit override (env/config) wins — honored verbatim, even for
-    ``pallas-triton``; otherwise the fastest *compiled* tier available
-    on this host (``tpu``, then ``pallas-triton``), else ``ref`` —
-    never a probed ``interpret``.
+    Explicit override (env/config) wins, honored verbatim; otherwise
+    ``tpu`` on a TPU backend and ``ref`` on any other — never
+    ``interpret`` unless asked for.
     """
     explicit = compat.explicit_kernel_tier()
     if explicit is not None:
         return explicit
-    for tier in ("tpu", "pallas-triton"):
-        if compat.tier_available(tier):
-            return tier
-    return "ref"
+    return "tpu" if compat.is_tpu_backend() else "ref"

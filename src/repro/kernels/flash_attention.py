@@ -21,7 +21,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -37,14 +36,16 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = qi * qb + lax.iota(jnp.int32, qb)
+    q_first = qi * qb
+    q_pos = q_first + lax.iota(jnp.int32, qb)
     k_first = ki * kb
-    # block-level liveness (causal upper-triangle + window lower bound)
+    # block-level liveness (causal upper-triangle + window lower bound);
+    # scalar bounds, since Mosaic cannot index a vector by position
     live = k_first < kv_len
     if causal:
-        live &= k_first <= q_pos[-1]
+        live &= k_first <= q_first + qb - 1
     if window:
-        live &= (k_first + kb) > (q_pos[0] - window)
+        live &= (k_first + kb) > (q_first - window)
 
     @pl.when(live)
     def _compute():
@@ -122,7 +123,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sqp, d), v.dtype),
         interpret=interpret,
-        **compat.compiler_params_kwargs(
+        name="flash_attention",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(lens, q, k, v)
     return out[:, :, :Sq]

@@ -1,91 +1,72 @@
-"""Public kernel entry points, routed through the four-tier dispatcher.
+"""Public kernel entry points, routed through the three-tier dispatcher.
 
 Every kernel resolves to one of the tiers registered in
 :mod:`repro.kernels.dispatch` — ``tpu`` (compiled Pallas),
-``pallas-triton`` (backend-agnostic Pallas lowered through Triton on
-GPU), ``interpret`` (Pallas interpreter; CPU numerics validation),
-``ref`` (pure-jnp from :mod:`repro.kernels.ref`, block-skipping for the
+``interpret`` (Pallas interpreter; CPU numerics validation), ``ref``
+(pure-jnp from :mod:`repro.kernels.ref`, block-skipping for the
 attention kernels). The process default comes from
 :func:`repro.compat.kernel_tier`; per-call overrides take ``tier=`` (or
 the legacy ``interpret=`` bool, mapped to ``interpret``/``tpu``).
-
-The Pallas implementations are only imported when the corresponding
-Pallas module itself imports — on a JAX build without it, every kernel
-still works at the ``ref`` tier.
 """
 from __future__ import annotations
 
-from repro import compat
 from repro.kernels import ref
+from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.dispatch import (DISPATCHER, coerce_tier, model_tier,
                                     register)
-
-if compat.HAS_PALLAS_TPU:
-    from repro.kernels.decode_attention import decode_attention as _decode_pallas
-    from repro.kernels.flash_attention import flash_attention as _flash_pallas
-    from repro.kernels.sliced_matmul import sliced_matmul as _sliced_pallas
-    from repro.kernels.subnet_rmsnorm import subnet_rmsnorm as _rmsnorm_pallas
-
-    @register("flash_attention", "tpu")
-    def _flash_tpu(q, k, v, *, causal, window, kv_len, q_block, kv_block):
-        return _flash_pallas(q, k, v, causal=causal, window=window,
-                             kv_len=kv_len, q_block=q_block,
-                             kv_block=kv_block, interpret=False)
-
-    @register("flash_attention", "interpret")
-    def _flash_interpret(q, k, v, *, causal, window, kv_len, q_block, kv_block):
-        return _flash_pallas(q, k, v, causal=causal, window=window,
-                             kv_len=kv_len, q_block=q_block,
-                             kv_block=kv_block, interpret=True)
-
-    @register("decode_attention", "tpu")
-    def _decode_tpu(q, k_cache, v_cache, index, *, window, kv_block):
-        return _decode_pallas(q, k_cache, v_cache, index, window=window,
-                              kv_block=kv_block, interpret=False)
-
-    @register("decode_attention", "interpret")
-    def _decode_interpret(q, k_cache, v_cache, index, *, window, kv_block):
-        return _decode_pallas(q, k_cache, v_cache, index, window=window,
-                              kv_block=kv_block, interpret=True)
-
-    @register("sliced_matmul", "tpu")
-    def _sliced_tpu(x, w, active_in, active_out, *, bm, bk, bn):
-        return _sliced_pallas(x, w, active_in, active_out, bm=bm, bk=bk,
-                              bn=bn, interpret=False)
-
-    @register("sliced_matmul", "interpret")
-    def _sliced_interpret(x, w, active_in, active_out, *, bm, bk, bn):
-        return _sliced_pallas(x, w, active_in, active_out, bm=bm, bk=bk,
-                              bn=bn, interpret=True)
-
-    @register("subnet_rmsnorm", "tpu")
-    def _rmsnorm_tpu(x, gamma_table, subnet_id, *, eps):
-        return _rmsnorm_pallas(x, gamma_table, subnet_id, eps=eps,
-                               interpret=False)
-
-    @register("subnet_rmsnorm", "interpret")
-    def _rmsnorm_interpret(x, gamma_table, subnet_id, *, eps):
-        return _rmsnorm_pallas(x, gamma_table, subnet_id, eps=eps,
-                               interpret=True)
+from repro.kernels.flash_attention import flash_attention as _flash_pallas
+from repro.kernels.sliced_matmul import sliced_matmul as _sliced_pallas
+from repro.kernels.subnet_rmsnorm import subnet_rmsnorm as _rmsnorm_pallas
 
 
-if compat.HAS_PALLAS_TRITON and compat.HAS_PALLAS:
-    from repro.kernels import triton_kernels as _triton
+@register("flash_attention", "tpu")
+def _flash_tpu(q, k, v, *, causal, window, kv_len, q_block, kv_block):
+    return _flash_pallas(q, k, v, causal=causal, window=window,
+                         kv_len=kv_len, q_block=q_block,
+                         kv_block=kv_block, interpret=False)
 
-    @register("flash_attention", "pallas-triton")
-    def _flash_triton(q, k, v, *, causal, window, kv_len, q_block, kv_block):
-        return _triton.flash_attention(q, k, v, causal=causal, window=window,
-                                       kv_len=kv_len, q_block=q_block,
-                                       kv_block=kv_block)
 
-    @register("sliced_matmul", "pallas-triton")
-    def _sliced_triton(x, w, active_in, active_out, *, bm, bk, bn):
-        return _triton.sliced_matmul(x, w, active_in, active_out, bm=bm,
-                                     bk=bk, bn=bn)
+@register("flash_attention", "interpret")
+def _flash_interpret(q, k, v, *, causal, window, kv_len, q_block, kv_block):
+    return _flash_pallas(q, k, v, causal=causal, window=window,
+                         kv_len=kv_len, q_block=q_block,
+                         kv_block=kv_block, interpret=True)
 
-    @register("subnet_rmsnorm", "pallas-triton")
-    def _rmsnorm_triton(x, gamma_table, subnet_id, *, eps):
-        return _triton.subnet_rmsnorm(x, gamma_table, subnet_id, eps=eps)
+
+@register("decode_attention", "tpu")
+def _decode_tpu(q, k_cache, v_cache, index, *, window, kv_block):
+    return _decode_pallas(q, k_cache, v_cache, index, window=window,
+                          kv_block=kv_block, interpret=False)
+
+
+@register("decode_attention", "interpret")
+def _decode_interpret(q, k_cache, v_cache, index, *, window, kv_block):
+    return _decode_pallas(q, k_cache, v_cache, index, window=window,
+                          kv_block=kv_block, interpret=True)
+
+
+@register("sliced_matmul", "tpu")
+def _sliced_tpu(x, w, active_in, active_out, *, bm, bk, bn):
+    return _sliced_pallas(x, w, active_in, active_out, bm=bm, bk=bk,
+                          bn=bn, interpret=False)
+
+
+@register("sliced_matmul", "interpret")
+def _sliced_interpret(x, w, active_in, active_out, *, bm, bk, bn):
+    return _sliced_pallas(x, w, active_in, active_out, bm=bm, bk=bk,
+                          bn=bn, interpret=True)
+
+
+@register("subnet_rmsnorm", "tpu")
+def _rmsnorm_tpu(x, gamma_table, subnet_id, *, eps):
+    return _rmsnorm_pallas(x, gamma_table, subnet_id, eps=eps,
+                           interpret=False)
+
+
+@register("subnet_rmsnorm", "interpret")
+def _rmsnorm_interpret(x, gamma_table, subnet_id, *, eps):
+    return _rmsnorm_pallas(x, gamma_table, subnet_id, eps=eps,
+                           interpret=True)
 
 
 @register("flash_attention", "ref")
@@ -153,15 +134,11 @@ def subnet_rmsnorm(x, gamma_table, subnet_id, *, eps=1e-5, tier=None,
 # --------------------------------------------------------------------------
 
 
-def _tier_registered(name: str, tier: str) -> bool:
-    return tier in DISPATCHER.registered_tiers(name)
-
-
 def model_flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
                           kv_len=None, q_block=512, kv_block=512, scale=None):
     """Full-sequence attention for model forward passes.
 
-    Pallas kernel (TPU or pallas-triton) when the model tier says so;
+    Pallas kernel when the model tier says so;
     the block-skipping XLA path from :mod:`repro.models.attention`
     otherwise (same math, asserted equal by the kernel tests). The
     Pallas kernels do not take ``q_offset``/``scale`` — calls using
@@ -171,8 +148,7 @@ def model_flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
     """
     tier = model_tier()
     pallas_ok = isinstance(q_offset, int) and q_offset == 0 and scale is None
-    if pallas_ok and tier != "ref" and _tier_registered("flash_attention",
-                                                        tier):
+    if pallas_ok and tier != "ref":
         return flash_attention(q, k, v, causal=causal, window=window,
                                kv_len=kv_len, q_block=q_block,
                                kv_block=kv_block, tier=tier)
@@ -184,14 +160,10 @@ def model_flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
 
 def model_decode_attention(q, k_cache, v_cache, *, index, window=0,
                            kv_block=512):
-    """Single-token cached decode for model decode steps.
-
-    ``pallas-triton`` registers no decode kernel (the GPU tier covers
-    the three hot prefill-path kernels); a tier with no registration
-    falls to the XLA path rather than erroring.
-    """
+    """Single-token cached decode for model decode steps: the Pallas
+    kernel when the model tier says so, the XLA path otherwise."""
     tier = model_tier()
-    if tier != "ref" and _tier_registered("decode_attention", tier):
+    if tier != "ref":
         return decode_attention(q, k_cache, v_cache, index, window=window,
                                 kv_block=kv_block, tier=tier)
     from repro.models.attention import decode_attention as xla_decode
@@ -201,7 +173,7 @@ def model_decode_attention(q, k_cache, v_cache, *, index, window=0,
 def model_subnet_rmsnorm(x, gamma_table, subnet_id, *, eps=1e-5):
     """SubnetNorm (RMS flavor) for model blocks; None = use XLA path."""
     tier = model_tier()
-    if tier != "ref" and _tier_registered("subnet_rmsnorm", tier):
+    if tier != "ref":
         return subnet_rmsnorm(x, gamma_table, subnet_id, eps=eps, tier=tier)
     return None
 

@@ -20,7 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 
 def _kernel(nact_ref, x_ref, w_ref, o_ref, acc_ref, *, bk: int, nk: int):
@@ -98,7 +97,8 @@ def sliced_matmul(x, w, active_in, active_out, *, bm: int = 128, bk: int = 128,
         ),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=interpret,
-        **compat.compiler_params_kwargs(
+        name="sliced_matmul",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(nact, x2, wp)
     out = out[:M, :N]

@@ -5,6 +5,10 @@ This is SubNetAct's actuation cost made explicit at the kernel level:
 switching subnets changes *one scalar*, which re-routes a single (1, d)
 DMA — no weight movement, no recompilation, < 1 microsecond of extra
 traffic (paper Fig 5b's "near-instantaneous actuation").
+
+The table is viewed as ``(n_subnets, 1, d)`` so the gain block's last
+two dims equal the array's: a ``(1, d)`` block over ``(n_subnets, d)``
+breaks Mosaic's (8, 128) tiling rule for any ``n_subnets != 1``.
 """
 from __future__ import annotations
 
@@ -20,7 +24,7 @@ def _kernel(sid_ref, x_ref, g_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + eps)
-    o_ref[...] = (y * g_ref[0].astype(jnp.float32)[None, :]).astype(o_ref.dtype)
+    o_ref[...] = (y * g_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "eps", "interpret"))
@@ -47,11 +51,13 @@ def subnet_rmsnorm(x, gamma_table, subnet_id, *, bm: int = 256,
             in_specs=[
                 pl.BlockSpec((bm_eff, d), lambda i, sid: (i, 0)),
                 # the actuation: subnet_id routes the gain-row DMA
-                pl.BlockSpec((1, d), lambda i, sid: (sid[0], 0)),
+                pl.BlockSpec((pl.squeezed, 1, d),
+                             lambda i, sid: (sid[0], 0, 0)),
             ],
             out_specs=pl.BlockSpec((bm_eff, d), lambda i, sid: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((M + pm, d), x.dtype),
         interpret=interpret,
-    )(sid, x2, gamma_table)
+        name="subnet_rmsnorm",
+    )(sid, x2, gamma_table.reshape(gamma_table.shape[0], 1, d))
     return out[:M].reshape(orig_shape)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input
 shape) cell on the production meshes with ShapeDtypeStruct inputs (no
 allocation), then extract memory_analysis / cost_analysis / collective
@@ -12,10 +9,12 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --all [--mesh both]
 
 Results land in results/dryrun/<arch>__<shape>__<mesh>.json; failures
-are sharding bugs by definition and fail loudly.
+are sharding bugs by definition and fail loudly. ``main()`` asks the
+CPU backend for 512 fake devices before JAX first touches one.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from functools import partial
@@ -23,7 +22,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import SHAPES, get_config, assigned_archs, shape_applicable
 from repro.core import subnet as sn
 from repro.distributed.sharding import ShardingPlan
@@ -193,7 +191,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
         t_compile = time.time() - t0 - t_lower
 
     ma = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     text = compiled.as_text()
     coll_bytes, breakdown = hlo_mod.collective_bytes(text)
     counts = hlo_mod.collective_count(text)
@@ -270,6 +268,7 @@ def _save(rec: dict) -> None:
 
 
 def main():
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -5,20 +5,22 @@ touches jax device state. The dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import so the factory can build the (2, 16, 16) multi-pod mesh on CPU.
 
-Mesh construction goes through :mod:`repro.compat` — the
-``axis_types=``/``AxisType`` surface only exists on newer JAX releases.
+Every axis is ``Auto``: the sharding rules in distributed/sharding.py
+place arrays with ``NamedSharding``s and let the partitioner do the rest.
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, examples, degraded pools)."""
-    return compat.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

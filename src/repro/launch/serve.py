@@ -35,16 +35,22 @@ and ``--execute real`` makes each child build its own AOT-warmed
 workers (optionally ``--work-ms`` of real CPU spin per batch) remain
 the default stand-in; arrivals are capped at ``--queries``. Still
 incompatible with ``--profile measured``, ``--faults`` and
-``--replica-deaths`` (fault scripts stay inproc/simulated).
+``--replica-deaths`` (fault scripts stay inproc/simulated). Children
+that run JAX are CPU-only (``JAX_PLATFORMS=cpu``; ``ipc.replica_env``):
+a chip belongs to one process. So ``--execute real`` children build the
+config's ``reduced()`` twin, and the coordinator profiles that twin.
 
 Compiled execution path (serving/executor.py): ``--execute real`` runs
-actual subnet forward passes on this host — the reduced config behind
-the AOT-warmed, shape-bucketed ``SubnetExecutor``, served by the
-asyncio Router/ClusterRouter with the SAME engine/policy/residency
-stack as the simulator. ``--profile measured`` replaces the analytic
-roofline ``LatencyProfile`` with wall-clock per-(subnet, batch-bucket)
-latencies measured through the warmed executor (usable with either
-``--execute`` mode). Both need a token-frontend LM arch, e.g.
+actual subnet forward passes on this host's devices — the config at its
+published widths and dtype behind the AOT-warmed, shape-bucketed
+``SubnetExecutor``, served by the asyncio Router/ClusterRouter with the
+SAME engine/policy/residency stack as the simulator, from a profile
+measured through the warmed executor (``--profile measured``, its
+default; usable with ``--execute sim`` too). With ``--replicas N`` each
+replica gets its own executor on ``jax.local_devices()[r % n]``: one
+process drives every local chip. ``--reduced`` swaps in the config's
+``reduced()`` twin (CPU tests and examples ask for it; nothing chooses
+it from the platform). Both need a token-frontend LM arch, e.g.
 ``--arch qwen2-1.5b``.
 """
 from __future__ import annotations
@@ -53,9 +59,12 @@ import argparse
 import asyncio
 import json
 import time
+from dataclasses import dataclass
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import compat
 from repro.configs import get_config
 from repro.serving import cluster, policies, profiler, simulator, traces
 from repro.serving.autoscaler import SCALINGS, AutoscaleConfig
@@ -73,27 +82,121 @@ def _host_latency(executor, subnet_idx: int, seq_len: int,
     return best
 
 
-def _serve_real(args, cfg, prof, pol, executor, arr, slo_s, rate, warm):
+@dataclass
+class RealRun:
+    """One ``--execute real`` run: the launcher's JSON (``out``) and the
+    objects behind it, for in-process callers that check the results
+    (chip_smoke.py)."""
+
+    out: Dict[str, Any]
+    executors: List[Any]                 # per replica (shared per device)
+    profile: "profiler.LatencyProfile"   # what the engine scheduled from
+    raw_profile: "profiler.LatencyProfile"  # the measurement, no cummax
+    payloads: np.ndarray                 # (queries, seq_len) prompt tokens
+    results: List[Tuple[Any, float]]     # (logits row | None, acc) each
+
+
+def _device_json(dev) -> Dict[str, Any]:
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "id": dev.id}
+
+
+def _measure(args, executors) -> Tuple["profiler.LatencyProfile",
+                                       "profiler.LatencyProfile", list]:
+    """AOT-warm every distinct executor's lattice, then measure the raw
+    per-(subnet, batch) table through the first; the engine schedules
+    from its monotonized copy."""
+    batches = (1, 2, 4, 8)
+    distinct = list({id(ex): ex for ex in executors}.values())
+    warm = [ex.warmup(batches=batches, seqs=(args.seq_len,))
+            for ex in distinct]
+    raw = executors[0].measured_profile(batches=batches,
+                                        seq_len=args.seq_len,
+                                        monotonize=False)
+    return profiler.monotonized(raw), raw, warm
+
+
+def _policy(args, prof):
+    if args.policy == "clipper":
+        idx = args.clipper_idx if args.clipper_idx >= 0 else prof.n_pareto - 1
+        return policies.ClipperFixed(idx)
+    return policies.ALL_POLICIES[args.policy]()
+
+
+def _trace(args, rate: float, duration: float) -> np.ndarray:
+    if args.trace == "bursty":
+        return traces.bursty_trace(rate * 0.2, rate * 0.8, args.cv2,
+                                   duration, args.seed)
+    if args.trace == "time_varying":
+        return traces.time_varying_trace(rate * 0.4, rate, args.tau,
+                                         args.cv2, duration, args.seed)
+    return traces.maf_like_trace(rate, duration, seed=args.seed)
+
+
+def config_of(args):
+    """``--arch``'s config at its published widths, or its reduced twin
+    when ``--reduced`` asks for it. Proc children that execute run on the
+    CPU and build the reduced twin (``replica_proc.make_real_workers``),
+    so ``--transport proc --execute real`` schedules its Pareto set."""
+    cfg = get_config(args.arch)
+    if args.reduced or (args.transport == "proc" and args.execute == "real"):
+        return cfg.reduced()
+    return cfg
+
+
+def run_real(args) -> RealRun:
+    """``--execute real``: build one executor per replica from
+    ``--seed``, AOT-warm, measure the profile, pace the trace to the
+    measured latencies, and serve it — all in this process."""
+    from repro.serving.executor import build_replica_executors
+
+    cfg = config_of(args)
+    executors = build_replica_executors(cfg, args.replicas, seed=args.seed)
+    prof, raw, warm = _measure(args, executors)
+    pol = _policy(args, prof)
+    # host-safe pacing: derive rate/SLO from latencies observed here
+    # (examples/serve_bursty.py sizing: SLO ~= 25x the max-subnet B=1
+    # latency, rate leaves 4x headroom on the min-subnet latency)
+    rate, slo_ms = args.rate, args.slo_ms
+    if rate is None:
+        rate = 0.25 / _host_latency(executors[0], 0, args.seq_len)
+    if slo_ms is None:
+        slo_ms = 25e3 * _host_latency(executors[0],
+                                      executors[0].n_subnets - 1,
+                                      args.seq_len)
+    arr = np.asarray(_trace(args, rate, args.queries / max(rate, 1e-9)),
+                     dtype=float)[: args.queries]
+    out, payloads, results = _serve_real(args, cfg, prof, pol, executors,
+                                         arr, slo_ms / 1e3, rate)
+    out.update({
+        "warmup": warm,
+        "profile_batches": list(prof.batches),
+        "profile_ms": (prof.lat * 1e3).tolist(),
+        "profile_raw_ms": (raw.lat * 1e3).tolist()})
+    return RealRun(out, executors, prof, raw, payloads, results)
+
+
+def _serve_real(args, cfg, prof, pol, executors, arr, slo_s, rate):
     """Serve ``arr`` with real forward passes through the asyncio
-    router(s); scheduling stays entirely inside the unchanged engine."""
-    from repro import compat
+    router(s), replica ``r`` on ``executors[r]``; scheduling stays
+    entirely inside the unchanged engine."""
     from repro.serving import runtime
 
+    rng = np.random.default_rng(args.seed)
+    payloads = rng.integers(0, cfg.vocab_size,
+                            (len(arr), args.seq_len)).astype(np.int32)
+
     async def go():
-        rng = np.random.default_rng(args.seed)
-        payloads = rng.integers(0, cfg.vocab_size,
-                                (len(arr), args.seq_len)).astype(np.int32)
         if args.replicas > 1:
             router = runtime.ClusterRouter(
-                prof, pol,
-                [executor.make_workers(args.workers)
-                 for _ in range(args.replicas)],
+                prof, pol, [ex.make_workers(args.workers)
+                            for ex in executors],
                 placement=args.placement, placement_seed=args.seed,
                 slo=slo_s)
         else:
             router = runtime.Router(prof, pol,
-                                    executor.make_workers(args.workers),
-                                    executor=executor)
+                                    executors[0].make_workers(args.workers),
+                                    executor=executors[0])
         await router.start()
         base = compat.compile_events()
         t0 = time.perf_counter()
@@ -103,13 +206,11 @@ def _serve_real(args, cfg, prof, pol, executor, arr, slo_s, rate, warm):
             if t > now:
                 await asyncio.sleep(t - now)
             futs.append(await router.submit(payloads[i], slo_s=slo_s))
-        await asyncio.gather(*futs)
+        results = await asyncio.gather(*futs)
         await router.drain()
-        compiles = (None if base is None
-                    else compat.compile_events() - base)
-        return router, compiles
+        return router, results, compat.compile_events() - base
 
-    router, serve_compiles = asyncio.run(go())
+    router, results, serve_compiles = asyncio.run(go())
     st = router.stats()
     recs = router.records()
     lats = sorted(r.finish - r.arrival for r in recs
@@ -119,21 +220,32 @@ def _serve_real(args, cfg, prof, pol, executor, arr, slo_s, rate, warm):
         return (lats[min(int(q * len(lats)), len(lats) - 1)] * 1e3
                 if lats else None)
 
-    return {"arch": args.arch, "mode": "real",
-            "profile": args.profile_mode, "policy": pol.name,
-            "queries": len(recs), "replicas": args.replicas,
-            "workers": args.workers,
-            "rate_qps": round(rate, 1), "slo_ms": round(slo_s * 1e3, 3),
-            "slo_attainment": st["slo_attainment"],
-            "mean_acc": st["mean_acc"],
-            "p50_latency_ms": pct(0.50), "p99_latency_ms": pct(0.99),
-            "switch_rate": st["switch_rate"],
-            "actuation_seconds": st["actuation_seconds"],
-            # SubNetAct live: compiles observed while serving (None if
-            # the jax.monitoring probe is unavailable); warmed serving
-            # should report 0
-            "serve_phase_compiles": serve_compiles,
-            "warmup": warm, "executor": executor.counters()}
+    out = {"arch": args.arch, "mode": "real",
+           "profile": args.profile_mode, "policy": pol.name,
+           "d_model": cfg.d_model, "vocab_size": cfg.vocab_size,
+           "dtype": cfg.dtype, "reduced": args.reduced,
+           "device": _device_json(executors[0].device),
+           "replica_devices": [str(ex.device) for ex in executors],
+           "queries": len(recs), "replicas": args.replicas,
+           "workers": args.workers,
+           "rate_qps": round(rate, 1), "slo_ms": round(slo_s * 1e3, 3),
+           "slo_attainment": st["slo_attainment"],
+           "mean_acc": st["mean_acc"],
+           "served": sum(1 for r in recs if not r.dropped),
+           "dropped": sum(1 for r in recs if r.dropped),
+           "p50_latency_ms": pct(0.50), "p99_latency_ms": pct(0.99),
+           "switch_rate": st["switch_rate"],
+           "actuation_seconds": st["actuation_seconds"],
+           # SubNetAct live: compiles observed while serving; warmed
+           # serving should report 0
+           "serve_phase_compiles": serve_compiles,
+           "executor": [ex.counters() for ex in
+                        {id(ex): ex for ex in executors}.values()]}
+    if args.replicas > 1:
+        served = [r.replica for r in recs if not r.dropped]
+        out["per_replica_served"] = {r: served.count(r)
+                                     for r in range(args.replicas)}
+    return out, payloads, list(results)
 
 
 def _serve_proc(args, cfg, prof, pol, arr, slo_s, rate, autoscale=None):
@@ -219,7 +331,7 @@ def _serve_proc(args, cfg, prof, pol, arr, slo_s, rate, autoscale=None):
     return out
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="ofa_resnet")
     ap.add_argument("--policy", default="slackfit",
@@ -237,21 +349,26 @@ def main():
     ap.add_argument("--execute", default="sim", choices=("sim", "real"),
                     help="sim: discrete-event simulation with profile "
                          "service times (default). real: execute actual "
-                         "subnet forward passes on this host through the "
-                         "AOT-warmed SubnetExecutor (serving/executor.py) "
-                         "behind the asyncio router — reduced config, "
-                         "token-frontend LM archs only; incompatible with "
-                         "--autoscale/--faults/--replica-deaths")
-    ap.add_argument("--profile", dest="profile_mode", default="analytic",
+                         "subnet forward passes on this host's devices "
+                         "through the AOT-warmed SubnetExecutor "
+                         "(serving/executor.py) behind the asyncio router "
+                         "— token-frontend LM archs only; incompatible "
+                         "with --autoscale/--faults/--replica-deaths")
+    ap.add_argument("--profile", dest="profile_mode", default=None,
                     choices=("analytic", "measured"),
                     help="latency profile the engine schedules from. "
                          "analytic: deterministic hardware-roofline model "
-                         "(profiler.build_profile, default). measured: "
-                         "true wall-clock per-(subnet, batch-bucket) "
-                         "latencies measured on this host through the "
-                         "warmed executor (token-frontend LM archs only; "
-                         "uses the reduced config; works with either "
-                         "--execute mode)")
+                         "of an RTX 2080 Ti (profiler.build_profile; the "
+                         "default for simulation and the proc transport). "
+                         "measured: wall-clock per-(subnet, batch-bucket) "
+                         "latencies measured on this host's device through "
+                         "the warmed executor (token-frontend LM archs "
+                         "only; the default, and the only choice, for "
+                         "inproc --execute real)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the config's reduced() twin (d_model 128, "
+                         "vocab 512, float32) instead of its published "
+                         "widths — for CPU tests and examples")
     ap.add_argument("--queries", type=int, default=64,
                     help="--execute real / --transport proc: number of "
                          "trace arrivals to serve (kept small — every "
@@ -343,7 +460,12 @@ def main():
                          "model) instead of the SubNetAct control swap — "
                          "the regime where --placement actuation_aware "
                          "and --policy slackfit_sticky earn their keep")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: Optional[List[str]] = None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
     if args.connect:
         # remote-replica child mode: this process serves frames for a
         # coordinator elsewhere; its ReplicaSpec arrives over the wire
@@ -358,7 +480,10 @@ def main():
         ap.error(f"--cold-start must be a number or 'auto', "
                  f"got {args.cold_start!r}")
 
-    cfg = get_config(args.arch)
+    cfg = config_of(args)
+    real_inproc = args.execute == "real" and args.transport != "proc"
+    if args.profile_mode is None:
+        args.profile_mode = "measured" if real_inproc else "analytic"
     if args.transport == "proc" and (
             args.profile_mode == "measured"
             or args.faults or args.replica_deaths):
@@ -368,81 +493,48 @@ def main():
     if args.listen and args.transport != "proc":
         ap.error("--listen is the proc transport's TCP front door; "
                  "add --transport proc")
-    executor, warm = None, None
     if args.execute == "real" or args.profile_mode == "measured":
         if cfg.family == "conv" or cfg.frontend != "token":
             ap.error(f"--execute real / --profile measured execute the "
                      f"LM path and need a token-frontend arch (try "
                      f"--arch qwen2-1.5b); {args.arch} is "
                      f"family={cfg.family}, frontend={cfg.frontend}")
-        if (args.execute == "real" and args.transport != "proc"
-                and (args.autoscale or args.faults
-                     or args.replica_deaths)):
+    if real_inproc:
+        if args.autoscale or args.faults or args.replica_deaths:
             ap.error("--execute real does not support --autoscale/"
                      "--faults/--replica-deaths inproc; --transport "
                      "proc runs autoscaled real execution, and the "
                      "simulator covers fault studies")
-        cfg = cfg.reduced()             # CPU-executable twin, same family
-        if args.transport != "proc":
-            # proc + real builds executors inside the children (from
-            # the same reduced config); the parent only profiles it
-            from repro.serving.executor import build_executor
-            executor = build_executor(cfg, seed=args.seed)
+        if args.profile_mode != "measured":
+            ap.error("inproc --execute real serves from the profile "
+                     "measured through its executors; the analytic "
+                     "profile models another device")
+        compat.enable_compile_cache()
+        print(json.dumps(run_real(args).out, indent=1))
+        return
 
     if args.profile_mode == "measured":
-        # AOT-warm first so measurement never times a compile
-        batches = (1, 2, 4, 8)
-        warm = executor.warmup(batches=batches, seqs=(args.seq_len,))
-        prof = executor.measured_profile(batches=batches,
-                                         seq_len=args.seq_len)
+        from repro.serving.executor import build_replica_executors
+        compat.enable_compile_cache()
+        prof = _measure(args, build_replica_executors(cfg, 1,
+                                                      seed=args.seed))[0]
     else:
         prof = profiler.build_profile(cfg)
-        if executor is not None:
-            # warm every bucket the analytic profile lets the policy
-            # choose, so serving stays compile-free
-            warm = executor.warmup(batches=prof.batches,
-                                   seqs=(args.seq_len,))
-
-    if args.policy == "clipper":
-        idx = args.clipper_idx if args.clipper_idx >= 0 else prof.n_pareto - 1
-        pol = policies.ClipperFixed(idx)
-    else:
-        pol = policies.ALL_POLICIES[args.policy]()
+    pol = _policy(args, prof)
 
     rate = args.rate if args.rate is not None else 7000.0
     slo_ms = args.slo_ms if args.slo_ms is not None else 36.0
     duration = args.duration
-    if args.execute == "real" and args.transport == "proc":
-        # the children execute; the parent has no executor to time —
-        # size pacing for reduced-config CPU forwards served over IPC
+    if args.execute == "real":
+        # proc + real: the children execute on their CPUs; the parent
+        # has no executor to time — pace for reduced-size CPU forwards
+        # served over IPC
         if args.rate is None:
             rate = 20.0
         if args.slo_ms is None:
             slo_ms = 4000.0
         duration = args.queries / max(rate, 1e-9)
-    elif args.execute == "real":
-        # host-safe pacing: the analytic roofline models the paper's
-        # 2080Ti, not this host — derive rate/SLO from latencies
-        # actually observed here (examples/serve_bursty.py sizing:
-        # SLO ~= 25x the max-subnet B=1 latency, rate leaves 4x
-        # headroom on the min-subnet latency)
-        lat_fast = _host_latency(executor, 0, args.seq_len)
-        lat_slow = _host_latency(executor, executor.n_subnets - 1,
-                                 args.seq_len)
-        if args.rate is None:
-            rate = 0.25 / lat_fast
-        if args.slo_ms is None:
-            slo_ms = lat_slow * 25 * 1e3
-        duration = args.queries / max(rate, 1e-9)
-
-    if args.trace == "bursty":
-        arr = traces.bursty_trace(rate * 0.2, rate * 0.8, args.cv2,
-                                  duration, args.seed)
-    elif args.trace == "time_varying":
-        arr = traces.time_varying_trace(rate * 0.4, rate, args.tau,
-                                        args.cv2, duration, args.seed)
-    else:
-        arr = traces.maf_like_trace(rate, duration, seed=args.seed)
+    arr = _trace(args, rate, duration)
 
     if args.transport == "proc":
         arr = np.asarray(arr, dtype=float)[: args.queries]
@@ -461,13 +553,6 @@ def main():
                    if args.scale_policy == "predictive" else {}))
         out = _serve_proc(args, cfg, prof, pol, arr, slo_ms / 1e3, rate,
                           autoscale)
-        print(json.dumps(out, indent=1))
-        return
-
-    if args.execute == "real":
-        arr = np.asarray(arr, dtype=float)[: args.queries]
-        out = _serve_real(args, cfg, prof, pol, executor, arr,
-                          slo_ms / 1e3, rate, warm)
         print(json.dumps(out, indent=1))
         return
 

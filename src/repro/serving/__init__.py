@@ -50,7 +50,7 @@ The live ClusterAutoscaler drives this transport too (spawn = fork or
 TCP-connect a process, decommission = drain frame through the
 coordinator's surrender path), and ``execute="real"`` children build
 their own AOT-warmed SubnetExecutor so completions carry real
-predictions. XLA host-device pinning via compat.host_devices_env.
+predictions. XLA host-device pinning via ipc.replica_env.
 Layering rule: the parent-side coordinator keeps sole ownership of
 admission/placement/lifecycle; children own scheduling through a full
 in-process Router; the transport only serializes placement decisions

@@ -1,5 +1,5 @@
 """Compiled-path subnet executor: AOT-warmed, shape-bucketed real
-execution behind the serving plane (ISSUE 8 tentpole).
+execution behind the serving plane.
 
 The paper's core claim is that SubNetAct actuates any point in the
 latency-accuracy space *near-instantaneously* because switching subnets
@@ -24,11 +24,14 @@ as an execution layer:
   hit/miss/compile/eviction counters (surfaced via
   ``Router.stats()["executor"]``).
 * **AOT lattice warmup** — :meth:`SubnetExecutor.warmup` pre-compiles
-  every bucket the profiler says the policy can choose through
-  ``compat.aot_compile`` (``jit(...).lower(...).compile()``), off the
-  serving critical path; on releases without the stages API it falls
-  back to eager first-call warmup. The first production query never
-  pays XLA compile.
+  every bucket the profiler says the policy can choose
+  (``jit(...).lower(...).compile()``), off the serving critical path.
+  A compile error raises where it happens. The first production query
+  never pays XLA compile.
+* **One device per executor** — params and the control stack are
+  committed to ``device`` (default: the first local device) and every
+  bucket compiles for it, so one process can host one executor per
+  local chip (``launch/serve.py --execute real --replicas N``).
 * **Buffer donation** — the decode cache is donated back to XLA where
   ``compat.donation_works()`` says the backend honors it, so steady
   decode runs in place instead of reallocating the KV cache per step.
@@ -62,7 +65,9 @@ from repro.kernels.dispatch import model_tier
 from repro.models import lm
 
 __all__ = ["ExecutorConfig", "SubnetExecutor", "DecodeCache",
-           "bucket_of", "build_executor", "build_serving_executor"]
+           "bucket_of", "init_params", "build_executor",
+           "build_replica_executors", "build_serving_executor",
+           "stacked_controls", "prefill_fn", "decode_fn"]
 
 
 def bucket_of(n: int, buckets: Sequence[int]) -> int:
@@ -88,7 +93,6 @@ class ExecutorConfig:
     seq_buckets: Tuple[int, ...] = (16, 32, 64, 128, 256)
     max_entries: int = 32               # LRU cap on compiled executables
     donate_cache: Optional[bool] = None  # None -> compat.donation_works()
-    use_aot: bool = True                # AOT warmup via compat.aot_compile
     slice_mode: str = "mask"
 
     def __post_init__(self):
@@ -114,16 +118,6 @@ class DecodeCache:
     state: Any = field(repr=False, default=None)
 
 
-class _Entry:
-    """One compiled (or jit-wrapped) executable in the LRU."""
-
-    __slots__ = ("fn", "aot")
-
-    def __init__(self, fn: Callable, aot: bool):
-        self.fn = fn
-        self.aot = aot
-
-
 class SubnetExecutor:
     """Executes real subnet forward passes for the serving plane.
 
@@ -135,23 +129,24 @@ class SubnetExecutor:
 
     def __init__(self, params: Dict, cfg: ArchConfig,
                  points: Optional[Sequence[ParetoPoint]] = None,
-                 exec_cfg: Optional[ExecutorConfig] = None):
-        self.params = params
+                 exec_cfg: Optional[ExecutorConfig] = None, device=None):
+        self.device = device if device is not None else jax.local_devices()[0]
+        self.sharding = jax.sharding.SingleDeviceSharding(self.device)
+        self.params = jax.device_put(params, self.device)
         self.cfg = cfg
         self.points: List[ParetoPoint] = list(points or pareto_subnets(cfg))
-        ctrls = [sn.make_control(cfg, p.sub) for p in self.points]
         # actuation == indexing this stack with a traced int32 — the
         # whole SubNetAct property hangs on ctrl being data, not shape
-        self.stacked_ctrl = {k: jnp.stack([jnp.asarray(c[k]) for c in ctrls])
-                             for k in ctrls[0]}
+        self.stacked_ctrl = jax.device_put(
+            stacked_controls(cfg, self.points), self.device)
         self.xcfg = exec_cfg or ExecutorConfig()
         self.donate = (self.xcfg.donate_cache
                        if self.xcfg.donate_cache is not None
                        else compat.donation_works())
-        self._cache: "OrderedDict[Tuple, _Entry]" = OrderedDict()
+        self._cache: "OrderedDict[Tuple, Callable]" = OrderedDict()
         self._lock = threading.RLock()
         self._counters = {"hits": 0, "misses": 0, "compiles": 0,
-                          "evictions": 0, "aot_compiles": 0}
+                          "evictions": 0}
 
     # -- introspection ---------------------------------------------------
 
@@ -212,7 +207,8 @@ class SubnetExecutor:
         """Fresh decode cache at the bucketed (batch, capacity)."""
         Bb = bucket_of(batch, self.xcfg.batch_buckets)
         Sb = bucket_of(seq_cap, self.xcfg.seq_buckets)
-        state = lm.init_cache(self.cfg, Bb, Sb, dtype=self.cfg.dtype)
+        state = jax.device_put(
+            lm.init_cache(self.cfg, Bb, Sb, dtype=self.cfg.dtype), self.device)
         return DecodeCache(batch=Bb, seq_cap=Sb, state=state)
 
     def decode_step(self, subnet_idx: int, tokens, cache: DecodeCache,
@@ -312,94 +308,132 @@ class SubnetExecutor:
     def _get(self, kind: str, Bb: int, Sb: int) -> Callable:
         key = (kind, Bb, Sb, model_tier())
         with self._lock:
-            entry = self._cache.get(key)
-            if entry is not None:
+            fn = self._cache.get(key)
+            if fn is not None:
                 self._cache.move_to_end(key)
                 self._counters["hits"] += 1
-                return entry.fn
+                return fn
             self._counters["misses"] += 1
-            entry = self._build(kind, Bb, Sb)
-            self._cache[key] = entry
+            fn = self._build(kind, Bb, Sb)
+            self._cache[key] = fn
             self._counters["compiles"] += 1
-            if entry.aot:
-                self._counters["aot_compiles"] += 1
             while len(self._cache) > self.xcfg.max_entries:
                 self._cache.popitem(last=False)
                 self._counters["evictions"] += 1
-            return entry.fn
+            return fn
 
-    def _build(self, kind: str, Bb: int, Sb: int) -> _Entry:
+    def _build(self, kind: str, Bb: int, Sb: int) -> Callable:
         cfg, slice_mode = self.cfg, self.xcfg.slice_mode
         if kind == "prefill":
-            def fn(params, stacked, tokens, idx, lengths):
-                ctrl = {k: v[idx] for k, v in stacked.items()}
-                logits = lm.forward(params, cfg, {"tokens": tokens}, ctrl,
-                                    slice_mode=slice_mode)
-                # causal families: the pad never influences positions
-                # < length, so gathering at length-1 IS the unpadded
-                # answer (pinned per tier in tests/test_executor.py)
-                pos = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
-                return jnp.take_along_axis(
-                    logits, pos[:, None, None], axis=1)[:, 0]
-            jitted = jax.jit(fn)
-            shaped = (self._shaped(self.params), self._shaped(self.stacked_ctrl),
-                      jax.ShapeDtypeStruct((Bb, Sb), jnp.int32),
-                      jax.ShapeDtypeStruct((), jnp.int32),
-                      jax.ShapeDtypeStruct((Bb,), jnp.int32))
+            jitted = jax.jit(prefill_fn(cfg, slice_mode))
+            shaped = (self._shaped(self.params),
+                      self._shaped(self.stacked_ctrl),
+                      self._spec((Bb, Sb)), self._spec(()), self._spec((Bb,)))
         elif kind == "decode":
-            def fn(params, stacked, tokens, cache, idx, index):  # noqa: F811
-                ctrl = {k: v[idx] for k, v in stacked.items()}
-                return lm.decode_step(params, cfg, tokens, ctrl, cache,
-                                      index, slice_mode=slice_mode)
-            jitted = jax.jit(fn, donate_argnums=(3,) if self.donate else ())
-            state = lm.init_cache(cfg, Bb, Sb, dtype=cfg.dtype)
-            shaped = (self._shaped(self.params), self._shaped(self.stacked_ctrl),
-                      jax.ShapeDtypeStruct((Bb, 1), jnp.int32),
-                      self._shaped(state),
-                      jax.ShapeDtypeStruct((), jnp.int32),
-                      jax.ShapeDtypeStruct((), jnp.int32))
+            jitted = jax.jit(decode_fn(cfg, slice_mode),
+                             donate_argnums=(3,) if self.donate else ())
+            state = jax.eval_shape(
+                lambda: lm.init_cache(cfg, Bb, Sb, dtype=cfg.dtype))
+            shaped = (self._shaped(self.params),
+                      self._shaped(self.stacked_ctrl),
+                      self._spec((Bb, 1)), self._shaped(state),
+                      self._spec(()), self._spec(()))
         else:
             raise ValueError(f"unknown step kind {kind!r}")
-        if self.xcfg.use_aot:
-            compiled = compat.aot_compile(jitted, *shaped)
-            if compiled is not None:
-                return _Entry(compiled, aot=True)
-        # eager fallback: compile on first call (warmup() still pulls
-        # this off the critical path by touching every bucket)
-        if kind == "prefill":
-            jitted(self.params, self.stacked_ctrl,
-                   np.zeros((Bb, Sb), np.int32), np.int32(0),
-                   np.full((Bb,), Sb, np.int32))
-        return _Entry(jitted, aot=False)
+        return jitted.lower(*shaped).compile()
 
-    @staticmethod
-    def _shaped(tree):
+    def _spec(self, shape, dtype=jnp.int32) -> jax.ShapeDtypeStruct:
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self.sharding)
+
+    def _shaped(self, tree):
         return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype),
-            tree)
+            lambda a: self._spec(jnp.shape(a), jnp.result_type(a)), tree)
+
+
+def stacked_controls(cfg: ArchConfig,
+                     points: Sequence[ParetoPoint]) -> Dict[str, np.ndarray]:
+    """The Pareto subnets' control tuples stacked along a leading axis:
+    the executor's actuation input (indexed by a traced subnet id)."""
+    ctrls = [sn.make_control(cfg, p.sub) for p in points]
+    return {k: np.stack([np.asarray(c[k]) for c in ctrls]) for k in ctrls[0]}
+
+
+def prefill_fn(cfg: ArchConfig, slice_mode: str = "mask") -> Callable:
+    """The executor's prefill step, ``(params, stacked_ctrl, tokens (B,
+    S), subnet_idx, lengths (B,)) -> (B, vocab)`` logits at each row's
+    last real position; jit it (tests/test_tpu_compile.py compiles it
+    for a described TPU)."""
+    def fn(params, stacked, tokens, idx, lengths):
+        ctrl = {k: v[idx] for k, v in stacked.items()}
+        logits = lm.forward(params, cfg, {"tokens": tokens}, ctrl,
+                            slice_mode=slice_mode)
+        # causal families: the pad never influences positions < length,
+        # so gathering at length-1 IS the unpadded answer (pinned per
+        # tier in tests/test_executor.py)
+        pos = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
+        return jnp.take_along_axis(logits, pos[:, None, None], axis=1)[:, 0]
+    return fn
+
+
+def decode_fn(cfg: ArchConfig, slice_mode: str = "mask") -> Callable:
+    """The executor's decode step, ``(params, stacked_ctrl, tokens (B,
+    1), cache, subnet_idx, index) -> (logits, new_cache)``."""
+    def fn(params, stacked, tokens, cache, idx, index):
+        ctrl = {k: v[idx] for k, v in stacked.items()}
+        return lm.decode_step(params, cfg, tokens, ctrl, cache, index,
+                              slice_mode=slice_mode)
+    return fn
+
+
+def init_params(cfg: ArchConfig, seed: int = 0) -> Dict:
+    """Seeded supernet params for ``cfg``, initialized in one jitted
+    program on the default device."""
+    return jax.jit(lambda key: lm.init_model(key, cfg))(
+        jax.random.PRNGKey(seed))
 
 
 def build_executor(cfg: ArchConfig, seed: int = 0,
                    exec_cfg: Optional[ExecutorConfig] = None,
                    ) -> SubnetExecutor:
-    """Init supernet params for ``cfg`` and wrap them in an executor
-    (the ``launch/serve.py --execute real`` entry point)."""
-    params = lm.init_model(jax.random.PRNGKey(seed), cfg)
-    return SubnetExecutor(params, cfg, exec_cfg=exec_cfg)
+    """Init supernet params for ``cfg`` and wrap them in an executor on
+    the first local device."""
+    return SubnetExecutor(init_params(cfg, seed), cfg, exec_cfg=exec_cfg)
+
+
+def build_replica_executors(cfg: ArchConfig, n_replicas: int, seed: int = 0,
+                            exec_cfg: Optional[ExecutorConfig] = None,
+                            ) -> List[SubnetExecutor]:
+    """One executor per replica, replica ``r`` committed to
+    ``jax.local_devices()[r % n_devices]`` (the ``launch/serve.py
+    --execute real --replicas N`` path). Replicas that land on one
+    device share its executor, so no device holds two weight copies."""
+    params = init_params(cfg, seed)
+    devices = jax.local_devices()
+    by_device: Dict[Any, SubnetExecutor] = {}
+    out = []
+    for r in range(n_replicas):
+        dev = devices[r % len(devices)]
+        if dev not in by_device:
+            by_device[dev] = SubnetExecutor(params, cfg, exec_cfg=exec_cfg,
+                                            device=dev)
+        out.append(by_device[dev])
+    return out
 
 
 def build_serving_executor(arch: str, seq_len: int = 16,
                            batches: Sequence[int] = (1, 2, 4, 8),
-                           seed: int = 0) -> SubnetExecutor:
+                           seed: int = 0,
+                           reduced: bool = False) -> SubnetExecutor:
     """Registry-name entry point for serving children
     (``replica_proc --execute real``): build the supernet executor for
-    ``arch``'s REDUCED config — the CPU-executable twin whose small
-    vocab also keeps per-completion logits safely under the IPC frame
-    cap — and AOT-warm the ``batches`` x ``seq_len`` lattice so the
-    first submit frame never pays an XLA compile. The coordinator must
-    profile the same reduced config for Pareto-set agreement."""
+    ``arch`` at its published widths, or its ``reduced()`` twin when
+    the caller asks for it, and AOT-warm the ``batches`` x ``seq_len``
+    lattice so the first submit frame never pays an XLA compile. The
+    coordinator must profile the same config for Pareto-set agreement."""
     from repro.configs import get_config
-    cfg = get_config(arch).reduced()
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
     ex = build_executor(cfg, seed=seed)
     ex.warmup(batches=tuple(batches), seqs=(int(seq_len),))
     return ex

@@ -66,8 +66,9 @@ JSON-serializable; policies must be registry-constructible by name
 graceful decommission may be re-served by a survivor (at-least-once on
 death/decommission, exactly-once otherwise); ``execute="real"``
 requires the coordinator's ``LatencyProfile`` to be built from the SAME
-reduced config the children build (``get_config(arch).reduced()``) so
-both sides agree on the Pareto subnet set.
+config the children build (``get_config(arch).reduced()``) so both
+sides agree on the Pareto subnet set; children that run JAX are
+CPU-only (:func:`replica_env`).
 """
 from __future__ import annotations
 
@@ -75,6 +76,7 @@ import asyncio
 import hashlib
 import hmac
 import json
+import os
 import secrets
 import subprocess
 import sys
@@ -537,21 +539,47 @@ def _src_root() -> str:
     return str(Path(repro.__file__).resolve().parent.parent)
 
 
-def spawn_replica_proc(spec: ReplicaSpec) -> subprocess.Popen:
-    """Start one replica worker process connected by a socketpair.
+def replica_env(spec: ReplicaSpec) -> Dict[str, str]:
+    """Env for a locally spawned replica process: the parent's own env,
+    so the child inherits its platform (``JAX_PLATFORMS`` included), the
+    parent's source tree on ``PYTHONPATH``, and with
+    ``spec.host_devices > 0`` an ``XLA_FLAGS`` request for that many CPU
+    devices, in place before the child's first jax import.
 
-    The env comes from ``compat.host_devices_env`` (CPU-pinned,
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` when the spec
-    pins fake devices) — set *before* the child ever imports jax, which
-    is the whole point of the process split on CPU CI. The parent-side
-    socket rides on ``proc._ipc_sock``. The inherited fd is trusted:
-    no handshake (only a process the coordinator itself spawned can
-    hold the other end)."""
+    A child that runs JAX (real execution or fake host devices) must be
+    pinned to the CPU by the parent's env: a chip belongs to one process
+    at a time, so children inheriting an accelerator platform would
+    contend for the chip. That is refused here, before any spawn; on a
+    chip host, ``launch/serve.py --execute real --replicas N`` serves
+    every local device from one process instead."""
+    env = dict(os.environ)
+    uses_jax = spec.execute == "real" or spec.host_devices > 0
+    if uses_jax and env.get("JAX_PLATFORMS") != "cpu":
+        raise RuntimeError(
+            "proc-transport children that run JAX need the parent's "
+            "JAX_PLATFORMS=cpu (found "
+            f"{env.get('JAX_PLATFORMS')!r}): a chip serves one process, "
+            "so accelerator children would contend for it. On a chip "
+            "host use --execute real --replicas N, which drives one "
+            "executor per local device from a single process")
+    paths = [_src_root()] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH")
+                             else [])
+    env["PYTHONPATH"] = os.pathsep.join(paths)
+    if spec.host_devices > 0:
+        pin = f"--xla_force_host_platform_device_count={spec.host_devices}"
+        env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {pin}".strip()
+    return env
+
+
+def spawn_replica_proc(spec: ReplicaSpec) -> subprocess.Popen:
+    """Start one replica worker process connected by a socketpair, with
+    :func:`replica_env`'s env. The parent-side socket rides on
+    ``proc._ipc_sock``. The inherited fd is trusted: no handshake (only
+    a process the coordinator itself spawned can hold the other end)."""
     import socket as socketlib
 
-    from repro.compat import host_devices_env   # deferred: imports jax
+    env = replica_env(spec)
     parent_sock, child_sock = socketlib.socketpair()
-    env = host_devices_env(spec.host_devices, PYTHONPATH=_src_root())
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.serving.replica_proc",
          "--fd", str(child_sock.fileno())],
@@ -568,8 +596,7 @@ def spawn_replica_proc_tcp(spec: ReplicaSpec, addr: Tuple[str, int],
     runs by hand (``replica_proc --connect HOST:PORT --token ...``).
     The token travels in the child env (``REPRO_IPC_TOKEN``), never on
     argv, so it stays out of process listings."""
-    from repro.compat import host_devices_env   # deferred: imports jax
-    env = host_devices_env(spec.host_devices, PYTHONPATH=_src_root())
+    env = replica_env(spec)
     env[TOKEN_ENV] = token
     host, port = addr
     return subprocess.Popen(

@@ -211,9 +211,20 @@ def measure_profile(step_fns: Sequence[Callable[[int], None]],
             for _ in range(iters):
                 fn(b)
             lat[i, j] = (time.perf_counter() - t0) / iters
-    if monotonize:
-        order = np.argsort(np.asarray(accs))
-        lat[order] = np.maximum.accumulate(lat[order], axis=0)    # P2
-        lat = np.maximum.accumulate(lat, axis=1)                  # P1
-    return LatencyProfile(arch=arch, accs=np.asarray(accs, float),
+    prof = LatencyProfile(arch=arch, accs=np.asarray(accs, float),
                           batches=tuple(batches), lat=lat, n_buckets=n_buckets)
+    return monotonized(prof) if monotonize else prof
+
+
+def monotonized(prof: LatencyProfile) -> LatencyProfile:
+    """``prof`` with the P1/P2 structure enforced: a cummax along
+    accuracy (a more accurate subnet is never faster) and along batch.
+    The raw table is the measurement; this is what the engine schedules
+    from, and the gap between the two is what the cummax hid."""
+    order = np.argsort(prof.accs)
+    lat = prof.lat.copy()
+    lat[order] = np.maximum.accumulate(lat[order], axis=0)    # P2
+    lat = np.maximum.accumulate(lat, axis=1)                  # P1
+    return LatencyProfile(arch=prof.arch, accs=prof.accs,
+                          batches=prof.batches, lat=lat,
+                          points=prof.points, n_buckets=prof.n_buckets)

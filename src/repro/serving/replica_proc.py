@@ -22,16 +22,18 @@ optional CPU spin (the scale-out benchmark's stand-in);
 ``spec.execute == "real"`` builds a ``SubnetExecutor`` in-child from
 ``get_config(spec.arch).reduced()`` (serving/executor.py), so
 completion frames carry real subnet logits and the engine's batch
-latencies are real forward passes.
+latencies are real forward passes. The child runs on the CPU, and the
+reduced twin's small vocab keeps each logits row far under the frame
+cap.
 
-Device pinning: the parent spawns this process with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` already in the
-env (``compat.host_devices_env`` — the HomebrewNLP-Jax/olmax idiom), so
-when the spec asks for fake devices (or real execution) the child's
-*first* jax import sees the flag and CPU CI gets an N-device host
-without TPUs. Nothing in this module (or the serving stack it imports)
-touches jax otherwise — the import happens here, after the flag is set,
-or not at all.
+Device pinning: the parent spawns this process with its own env plus
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` when the spec
+asks for fake devices (``ipc.replica_env`` — the HomebrewNLP-Jax/olmax
+idiom), so the child's *first* jax import sees the flag and CPU CI gets
+an N-device host without TPUs. A child that runs JAX is CPU-only.
+Nothing in this module (or the serving stack it imports) touches jax
+otherwise — the import happens here, after the flag is set, or not at
+all.
 
 Scheduling stays engine-owned: the child's router drops infeasible
 queries, forms batches, and re-enqueues on worker faults exactly as
@@ -80,7 +82,7 @@ def make_real_workers(spec: ReplicaSpec) -> List[WorkerHandle]:
     silently disagree across the boundary."""
     from repro.serving.executor import build_serving_executor
     ex = build_serving_executor(spec.arch, seq_len=spec.seq_len,
-                                seed=spec.seed)
+                                seed=spec.seed, reduced=True)
     profile = profile_from_wire(spec.profile)
     if ex.n_subnets != profile.lat.shape[0]:
         raise ValueError(

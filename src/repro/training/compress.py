@@ -16,7 +16,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -69,11 +68,11 @@ def all_reduce_int8(mesh: Mesh, grads: Any, err: Any, axis: str = "data"):
                 tdef.unflatten([o[1] for o in outs]))
 
     spec = jax.tree.map(lambda x: P(*([None] * x.ndim)), grads)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=((spec, spec),),
         out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )((grads, err))
 
 

@@ -1,10 +1,26 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — unit/smoke tests must see
 the real single CPU device; multi-device tests spawn subprocesses."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import ArchConfig, ElasticSpec, Stage
+
+
+def cpu_subprocess_env(**extra) -> dict:
+    """Minimal env for a test's CPU-pinned python subprocess. Without
+    ``JAX_PLATFORMS=cpu`` a host with the TPU library installed (but no
+    chip attached) stalls for minutes looking for one."""
+    env = {
+        "PYTHONPATH": "src",
+        "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+        "HOME": os.path.expanduser("~"),
+        "JAX_PLATFORMS": "cpu",
+    }
+    env.update(extra)
+    return env
 
 
 def tiny_dense(**kw) -> ArchConfig:

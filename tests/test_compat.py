@@ -1,6 +1,7 @@
-"""Compat shim resolution on the installed JAX + kernel dispatcher
-tiers: import sweep over every repro.* module, probe results, tier
-fallback chain, and per-kernel agreement between the fallback tiers.
+"""The installed JAX surfaces the repo calls directly + kernel
+dispatcher tiers: import sweep over every repro.* module, tier
+resolution, the compile cache switch, and per-kernel agreement between
+the tiers this host can run.
 """
 import importlib
 import os
@@ -38,54 +39,46 @@ def test_module_imports(name):
 
     This is the canary for version drift: the seed repo failed here on
     jax 0.4.37 (TPUCompilerParams rename, AxisType, AbstractMesh)."""
-    saved = os.environ.get("XLA_FLAGS")
-    try:
-        importlib.import_module(name)
-    finally:
-        # repro.launch.dryrun sets XLA_FLAGS at import; don't leak it
-        # into later tests' subprocess spawns.
-        if saved is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:
-            os.environ["XLA_FLAGS"] = saved
+    before = os.environ.get("XLA_FLAGS")
+    importlib.import_module(name)
+    # importing never changes the process environment
+    assert os.environ.get("XLA_FLAGS") == before
 
 
 # --------------------------------------------------------------------------
-# shim resolution on the installed version
+# installed surfaces, called directly
 # --------------------------------------------------------------------------
-
-
-def test_jax_version_parsed():
-    assert compat.JAX_VERSION >= (0, 4)
-    assert compat.JAX_VERSION == compat._version_tuple(jax.__version__)
 
 
 def test_compiler_params_resolve_on_this_version():
-    """Whatever this JAX calls the class, the shim must find it."""
-    assert compat.HAS_PALLAS and compat.HAS_PALLAS_TPU
-    params = compat.tpu_compiler_params(
+    """The kernels pass ``pltpu.CompilerParams`` directly: a field this
+    release lacks raises instead of being dropped in silence."""
+    from jax.experimental.pallas import tpu as pltpu
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
-    assert params is not None
-    kw = compat.compiler_params_kwargs(
-        dimension_semantics=("parallel", "arbitrary"))
-    assert set(kw) == {"compiler_params"}
-    # unknown fields are dropped, never raised
-    assert compat.tpu_compiler_params(not_a_real_field=1) is None
+    assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
+    with pytest.raises(TypeError):
+        pltpu.CompilerParams(not_a_real_field=1)
 
 
 def test_make_abstract_mesh_both_signatures():
-    mesh = compat.make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    from repro.configs import get_config
+    from repro.distributed.sharding import ShardingPlan
+    mesh = ShardingPlan.abstract((2, 16, 16), ("pod", "data", "model"),
+                                 get_config("qwen2-1.5b")).mesh
     assert tuple(mesh.axis_names) == ("pod", "data", "model")
     assert dict(mesh.shape) == {"pod": 2, "data": 16, "model": 16}
 
 
 def test_make_mesh_single_device():
-    mesh = compat.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     assert mesh.shape["data"] == 1
 
 
 def test_cpu_subprocess_env_pins_backend():
-    env = compat.cpu_subprocess_env(EXTRA="x")
+    from conftest import cpu_subprocess_env
+    env = cpu_subprocess_env(EXTRA="x")
     assert env["JAX_PLATFORMS"] == "cpu"
     assert env["PYTHONPATH"] == "src"
     assert env["EXTRA"] == "x"
@@ -110,7 +103,31 @@ def test_ref_tier_always_available():
 
 def test_interpret_probe_runs_here():
     # this repo's CI floor: the Pallas interpreter must work on CPU
-    assert compat.pallas_interpret_works()
+    assert compat.tier_available("interpret")
+    x = jnp.ones((8, 128), jnp.float32)
+    gt = jnp.full((2, 128), 2.0, jnp.float32)
+    y = ops.subnet_rmsnorm(x, gt, jnp.int32(1), tier="interpret")
+    np.testing.assert_allclose(np.asarray(y), 2.0, rtol=1e-5)
+
+
+def test_compile_cache_env_stays_in_charge(monkeypatch, tmp_path):
+    monkeypatch.setenv(compat.CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compat.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch):
+    monkeypatch.delenv(compat.CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = compat.enable_compile_cache()
+        assert path == compat.enable_compile_cache()      # stable
+        assert path.endswith(".jax_cache")
+        assert (compat.DEFAULT_CACHE_DIR.parent / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_set_kernel_tier_validates():
@@ -152,7 +169,7 @@ def test_set_kernel_tier_roundtrip():
 
 def test_model_tier_never_probed_interpret():
     if compat.explicit_kernel_tier() is None:
-        assert model_tier() in ("tpu", "pallas-triton", "ref")
+        assert model_tier() in ("tpu", "ref")
 
 
 def test_coerce_tier_legacy_interpret_flag():
@@ -171,9 +188,7 @@ def test_all_kernels_registered_all_tiers():
     assert set(KERNELS) <= set(DISPATCHER.kernels())
     for name in KERNELS:
         tiers = DISPATCHER.registered_tiers(name)
-        assert "ref" in tiers
-        if compat.HAS_PALLAS_TPU:
-            assert "tpu" in tiers and "interpret" in tiers
+        assert tiers == compat.KERNEL_TIERS
 
 
 def test_resolve_unknown_kernel_raises():
@@ -183,12 +198,14 @@ def test_resolve_unknown_kernel_raises():
         DISPATCHER.register("flash_attention", "not_a_tier", lambda: None)
 
 
-def test_resolve_falls_down_the_chain():
+def test_resolve_never_falls_down_the_chain():
     DISPATCHER.register("_chain_probe", "ref", lambda: "ref")
     try:
-        tier, fn = DISPATCHER.resolve("_chain_probe")
-        # process tier here is interpret (CPU) or tpu; either way the
-        # only registered tier is ref, and resolution must land on it.
+        # process tier here is interpret (CPU) or tpu; the only
+        # registered tier is ref, and resolution must not substitute it
+        with pytest.raises(KeyError, match="no 'interpret' tier|no 'tpu'"):
+            DISPATCHER.resolve("_chain_probe")
+        tier, fn = DISPATCHER.resolve("_chain_probe", "ref")
         assert tier == "ref" and fn() == "ref"
     finally:
         DISPATCHER._impls.pop("_chain_probe")
@@ -202,9 +219,8 @@ _TOL = dict(rtol=2e-3, atol=2e-3)
 
 
 def _host_tiers(name):
-    """Tiers executable on this host for ``name`` (compiled tiers need
-    their accelerator; CPU numerics for pallas-triton are covered via
-    interpret mode in tests/test_dispatch.py)."""
+    """Tiers executable on this host for ``name`` (the compiled tier
+    needs its TPU; interpret mode covers the kernel bodies on CPU)."""
     return [t for t in DISPATCHER.registered_tiers(name)
             if t != "ref" and compat.tier_available(t)]
 

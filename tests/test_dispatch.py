@@ -1,22 +1,16 @@
-"""pallas-triton tier wiring + block-skipping ref numerics.
+"""Kernel-tier resolution + block-skipping ref numerics.
 
-Three concerns, per the hot-path PR:
+Two concerns:
 
-* **Registry resolution** — the GPU tier is registered for the three
-  hot kernels and sits in the right place in the fallback chain.
-* **Probed degradation** — on a CPU host the probed chain lands below
-  ``pallas-triton`` (schedules and numerics identical to before the
-  tier existed), while ``REPRO_KERNEL_TIER=pallas-triton`` is honored
-  verbatim where available and fails *loudly* (never silently
+* **Resolution** — the tier follows the platform (``tpu`` on a TPU
+  backend; ``interpret`` for the process and ``ref`` for model paths
+  elsewhere), an explicit ``REPRO_KERNEL_TIER`` is honored verbatim
+  where the host can run it, and fails *loudly* (never silently
   substituted) where not.
-* **Numerics** — the backend-agnostic triton kernel bodies agree with
-  the dense oracles under the Pallas interpreter (how CPU CI validates
-  GPU kernels), and the block-skipping ref tier agrees with the dense
+* **Numerics** — the block-skipping ref tier agrees with the dense
   oracle across causal/window/kv_len corners (property-tested).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,63 +23,45 @@ from repro.kernels.dispatch import DISPATCHER, model_tier
 
 from _hypothesis_compat import given, settings, strategies as st
 
-TRITON_KERNELS = ("flash_attention", "sliced_matmul", "subnet_rmsnorm")
 _TOL = dict(rtol=2e-3, atol=2e-3)
 
 
 # --------------------------------------------------------------------------
-# registry resolution
-# --------------------------------------------------------------------------
-
-
-def test_pallas_triton_registered_for_hot_kernels():
-    if not compat.HAS_PALLAS_TRITON:
-        pytest.skip("no pallas.triton module in this jax build")
-    for name in TRITON_KERNELS:
-        assert "pallas-triton" in DISPATCHER.registered_tiers(name), name
-
-
-def test_pallas_triton_explicit_resolution():
-    """tier='pallas-triton' resolves to the triton impl (resolution
-    only — executing it needs a GPU)."""
-    if not compat.HAS_PALLAS_TRITON:
-        pytest.skip("no pallas.triton module in this jax build")
-    from repro.kernels import triton_kernels
-    tier, fn = DISPATCHER.resolve("flash_attention", "pallas-triton")
-    assert tier == "pallas-triton"
-    assert fn.__module__ == ops.__name__
-    # decode_attention deliberately has no GPU registration: the model
-    # wrapper must fall to the XLA path, not raise
-    assert "pallas-triton" not in DISPATCHER.registered_tiers(
-        "decode_attention")
-
-
-def test_chain_order_has_triton_between_tpu_and_interpret():
-    assert compat.KERNEL_TIERS == ("tpu", "pallas-triton", "interpret",
-                                   "ref")
-
-
-# --------------------------------------------------------------------------
-# probed degradation on CPU
+# resolution on CPU
 # --------------------------------------------------------------------------
 
 
 def test_probed_chain_skips_triton_off_gpu():
-    if compat.is_gpu_backend() or compat.is_tpu_backend():
-        pytest.skip("accelerator attached; probed chain differs")
-    assert not compat.tier_available("pallas-triton")
-    assert compat.kernel_tier() in ("interpret", "ref")
+    """Off a TPU the process tier is the interpreter and model paths
+    take the ref/XLA path; the ``tpu`` tier is not available."""
+    if compat.is_tpu_backend():
+        pytest.skip("TPU attached; the platform tier is tpu")
+    if compat.explicit_kernel_tier() is not None:
+        pytest.skip("explicit tier pinned in this process")
+    assert not compat.tier_available("tpu")
+    assert compat.kernel_tier() == "interpret"
     assert model_tier() == "ref"
     tier, _ = DISPATCHER.resolve("flash_attention", None)
-    assert tier in ("interpret", "ref")
+    assert tier == "interpret"
+
+
+def test_model_tier_follows_platform(monkeypatch):
+    """On a TPU backend model paths run the ``tpu`` kernels: no probe
+    can drop them to ``ref`` behind the operator's back."""
+    if compat.explicit_kernel_tier() is not None:
+        pytest.skip("explicit tier pinned in this process")
+    monkeypatch.setattr(compat, "is_tpu_backend", lambda: True)
+    assert model_tier() == "tpu"
+    monkeypatch.setattr(compat, "is_tpu_backend", lambda: False)
+    assert model_tier() == "ref"
 
 
 def test_model_calls_unchanged_by_triton_registration():
-    """Registering the GPU tier must leave CPU model numerics and
-    routing exactly as they were (the probed-degradation proof)."""
+    """CPU model attention routes to the block-skipping XLA path and
+    matches it exactly."""
     if compat.explicit_kernel_tier() is not None:
         pytest.skip("explicit tier pinned in this process")
-    if compat.is_gpu_backend() or compat.is_tpu_backend():
+    if compat.is_tpu_backend():
         pytest.skip("accelerator attached")
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (1, 4, 48, 16), jnp.float32)
@@ -99,24 +75,21 @@ def test_model_calls_unchanged_by_triton_registration():
 
 
 def test_env_override_honored_verbatim(monkeypatch):
-    """REPRO_KERNEL_TIER=pallas-triton pins process AND model tier when
-    the host can serve it."""
+    """REPRO_KERNEL_TIER=tpu pins process AND model tier when the host
+    can serve it."""
     real_avail = compat.tier_available
     monkeypatch.setattr(compat, "tier_available",
-                        lambda t: True if t == "pallas-triton"
-                        else real_avail(t))
-    monkeypatch.setenv("REPRO_KERNEL_TIER", "pallas-triton")
+                        lambda t: True if t == "tpu" else real_avail(t))
+    monkeypatch.setenv("REPRO_KERNEL_TIER", "tpu")
     compat.reset_kernel_tier()
     try:
-        assert compat.kernel_tier() == "pallas-triton"
-        assert compat.explicit_kernel_tier() == "pallas-triton"
-        assert model_tier() == "pallas-triton"
-        tier, _ = DISPATCHER.resolve("flash_attention", None)
-        assert tier == "pallas-triton"
-        # no GPU registration for decode -> chain falls through, and the
-        # model wrapper routes to XLA instead of raising
-        tier, _ = DISPATCHER.resolve("decode_attention", None)
-        assert tier in ("interpret", "ref")
+        assert compat.kernel_tier() == "tpu"
+        assert compat.explicit_kernel_tier() == "tpu"
+        assert model_tier() == "tpu"
+        for name in ("flash_attention", "decode_attention",
+                     "sliced_matmul", "subnet_rmsnorm"):
+            tier, _ = DISPATCHER.resolve(name, None)
+            assert tier == "tpu", name
     finally:
         compat.reset_kernel_tier()
 
@@ -124,67 +97,15 @@ def test_env_override_honored_verbatim(monkeypatch):
 def test_env_override_unavailable_fails_loudly(monkeypatch):
     """An explicit tier the host cannot serve raises instead of being
     silently swapped — 'verbatim or error', never 'verbatim-ish'."""
-    if compat.tier_available("pallas-triton"):
-        pytest.skip("GPU attached; the override would be legal here")
-    monkeypatch.setenv("REPRO_KERNEL_TIER", "pallas-triton")
+    if compat.tier_available("tpu"):
+        pytest.skip("TPU attached; the override would be legal here")
+    monkeypatch.setenv("REPRO_KERNEL_TIER", "tpu")
     compat.reset_kernel_tier()
     try:
         with pytest.raises(RuntimeError):
             compat.kernel_tier()
     finally:
         compat.reset_kernel_tier()
-
-
-# --------------------------------------------------------------------------
-# triton kernel numerics under the interpreter (CPU CI's GPU proxy)
-# --------------------------------------------------------------------------
-
-
-def _skip_without_pallas():
-    if not (compat.HAS_PALLAS and compat.HAS_PALLAS_TRITON):
-        pytest.skip("pallas/pallas.triton unavailable")
-
-
-def test_triton_flash_attention_interpret_numerics():
-    _skip_without_pallas()
-    from repro.kernels.triton_kernels import flash_attention
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    q = jax.random.normal(ks[0], (1, 4, 64, 32), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 2, 64, 32), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 2, 64, 32), jnp.float32)
-    for window in (0, 16):
-        for kv_len in (None, 40):
-            got = flash_attention(q, k, v, causal=True, window=window,
-                                  kv_len=kv_len, q_block=32, kv_block=32,
-                                  interpret=True)
-            want = ref.flash_attention_dense_ref(q, k, v, causal=True,
-                                                 window=window, kv_len=kv_len)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       **_TOL)
-
-
-def test_triton_sliced_matmul_interpret_numerics():
-    _skip_without_pallas()
-    from repro.kernels.triton_kernels import sliced_matmul
-    x = jax.random.normal(jax.random.PRNGKey(3), (2, 16, 96), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(4), (96, 128), jnp.float32)
-    for ai, ao in ((96, 128), (48, 80), (33, 1)):
-        got = sliced_matmul(x, w, jnp.int32(ai), jnp.int32(ao),
-                            bm=32, bk=32, bn=32, interpret=True)
-        want = ref.sliced_matmul_ref(
-            x.reshape(-1, 96), w, ai, ao).reshape(2, 16, 128)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
-
-
-def test_triton_subnet_rmsnorm_interpret_numerics():
-    _skip_without_pallas()
-    from repro.kernels.triton_kernels import subnet_rmsnorm
-    x = jax.random.normal(jax.random.PRNGKey(5), (40, 64), jnp.float32)
-    gt = jax.random.normal(jax.random.PRNGKey(6), (3, 64), jnp.float32)
-    for sid in (0, 2):
-        got = subnet_rmsnorm(x, gt, jnp.int32(sid), bm=16, interpret=True)
-        want = ref.subnet_rmsnorm_ref(x, gt, jnp.int32(sid))
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
 
 
 # --------------------------------------------------------------------------

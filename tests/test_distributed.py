@@ -15,8 +15,7 @@ from repro.configs import get_config
 
 def _abstract_plan(arch, shape=(2, 16, 16), axes=("pod", "data", "model")):
     from repro.distributed.sharding import ShardingPlan
-    # compat.make_abstract_mesh under the hood: the AbstractMesh
-    # constructor signature differs across JAX versions.
+    # a device-free AbstractMesh: the rules need no devices
     return ShardingPlan.abstract(shape, axes, get_config(arch))
 
 
@@ -114,7 +113,7 @@ MULTIDEV = textwrap.dedent("""
 
 
 def test_multidevice_collectives_subprocess():
-    from repro.compat import cpu_subprocess_env
+    from conftest import cpu_subprocess_env
     r = subprocess.run([sys.executable, "-c", MULTIDEV], capture_output=True,
                        text=True, env=cpu_subprocess_env(),
                        cwd="/root/repo", timeout=300)
@@ -152,8 +151,7 @@ def test_mini_dryrun_subprocess():
                 sp["params"], sp["batch"], sp["ctrl"])
             compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        from repro import compat
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         cb, bd = H.collective_bytes(compiled.as_text())
         t = RooflineTerms(arch="mini", shape="mini_train", mesh="8dev",
                           chips=8, hlo_flops_per_device=ca["flops"],
@@ -167,7 +165,7 @@ def test_mini_dryrun_subprocess():
         print(json.dumps({"ok": True, "dominant": t.dominant,
                           "coll_bytes": cb}))
     """)
-    from repro.compat import cpu_subprocess_env
+    from conftest import cpu_subprocess_env
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=cpu_subprocess_env(),
                        cwd="/root/repo", timeout=600)

@@ -5,8 +5,9 @@ The load-bearing assertions lean on ``compat.CompileCounter`` — the
 ``jax.monitoring`` backend-compile listener — so they prove the
 SubNetAct property (actuation never recompiles) and the bucketing
 property (the jit cache is bounded by the bucket lattice) against the
-real XLA compile pipeline, not proxies. Tests that need the probe skip
-cleanly on releases without ``jax.monitoring``.
+real XLA compile pipeline, not proxies. The launcher's in-process
+real-execution path (``launch/serve.py --execute real``) and the
+one-executor-per-device placement close the file.
 """
 import asyncio
 
@@ -23,11 +24,6 @@ from repro.models import lm
 from repro.serving.executor import (DecodeCache, ExecutorConfig,
                                     SubnetExecutor, bucket_of,
                                     build_executor)
-
-needs_probe = pytest.mark.skipif(
-    compat.compile_events() is None,
-    reason="jax.monitoring compile-event probe unavailable")
-
 
 # --------------------------------------------------------------------------
 # pure bucketing / config plumbing (no compilation)
@@ -75,7 +71,6 @@ def warmed():
     return ex
 
 
-@needs_probe
 def test_warmed_actuation_never_recompiles(warmed):
     """SubNetAct: >= 3 subnets x >= 3 batch shapes after warmup ->
     zero XLA compilations (the control tuple is traced data; raw
@@ -89,7 +84,6 @@ def test_warmed_actuation_never_recompiles(warmed):
     assert cc.count == 0
 
 
-@needs_probe
 def test_subnets_differ_through_one_executable(warmed):
     """The zero-compile path still actuates: different subnet indices
     give different logits through the same compiled entry."""
@@ -132,7 +126,6 @@ def test_router_stats_surface_executor_counters(warmed):
     assert 0.0 <= st["executor"]["hit_rate"] <= 1.0
 
 
-@needs_probe
 def test_real_router_serving_is_compile_free(warmed):
     """The acceptance probe end-to-end: an executor-backed Router
     serving across subnets and batch shapes triggers zero XLA
@@ -250,8 +243,7 @@ def test_decode_matches_reference_and_donates(warmed):
     assert (dc.batch, dc.seq_cap) == (2, 8)
     with compat.CompileCounter() as cc:
         logits, dc2 = warmed.decode_step(1, toks, dc, 0)
-    if cc.available:
-        assert cc.count == 0                       # warmed with decode=True
+    assert cc.count == 0                           # warmed with decode=True
     ctrl = sn.make_control(cfg, warmed.points[1].sub)
     state = lm.init_cache(cfg, 2, 8, dtype=cfg.dtype)
     ref_logits, _ = lm.decode_step(warmed.params, cfg, jnp.asarray(toks),
@@ -275,7 +267,6 @@ def test_decode_pads_small_batches_into_cache_bucket(warmed):
 # --------------------------------------------------------------------------
 
 
-@needs_probe
 def test_generate_compiles_decode_step_exactly_once():
     cfg = tiny_dense(d_ff=192)     # unique cfg -> cold decode-step cache
     params = lm.init_model(jax.random.PRNGKey(0), cfg)
@@ -296,3 +287,81 @@ def test_generate_compiles_decode_step_exactly_once():
                     max_new=2, seq_cap=8)
     assert again.count == 0
     assert out_a.shape == (1, prompt.shape[1] + 2)
+
+
+# --------------------------------------------------------------------------
+# launcher: in-process real execution, widths, one executor per device
+# --------------------------------------------------------------------------
+
+
+def _launch_args(*argv):
+    from repro.launch import serve
+    return serve.build_parser().parse_args(["--arch", "qwen2-1.5b", *argv])
+
+
+def test_launcher_full_width_unless_reduced_is_asked():
+    from repro.configs import get_config
+    from repro.launch import serve
+    full = get_config("qwen2-1.5b")
+    assert serve.config_of(_launch_args("--execute", "real")) == full
+    assert serve.config_of(_launch_args("--reduced")) == full.reduced()
+
+
+def test_launcher_real_execution_in_process():
+    """``--execute real`` serves every query through the warmed executor
+    in this process, compile-free, from the measured profile."""
+    from repro.launch import serve
+    run = serve.run_real(_launch_args(
+        "--execute", "real", "--reduced", "--profile", "measured",
+        "--queries", "12", "--seq-len", "16"))
+    out, n = run.out, len(run.payloads)
+    assert out["reduced"] and out["dtype"] == "float32"
+    assert len(run.results) == n and out["served"] + out["dropped"] == n
+    assert out["serve_phase_compiles"] == 0
+    assert out["replica_devices"] == [str(jax.devices()[0])]
+    # the engine's profile is the raw measurement with the cummax applied
+    assert (run.profile.lat >= run.raw_profile.lat).all()
+    for pred, _ in run.results:
+        if pred is not None:
+            assert np.asarray(pred).shape == (run.executors[0].cfg.vocab_size,)
+
+
+def test_launcher_refuses_full_width_proc_children():
+    """Proc children run on the CPU and build the reduced twin; the
+    coordinator schedules that twin's Pareto set, never the full one."""
+    from repro.configs import get_config
+    from repro.launch import serve
+    args = _launch_args("--transport", "proc", "--execute", "real")
+    assert serve.config_of(args) == get_config("qwen2-1.5b").reduced()
+
+
+REPLICA_DEVICES = """
+import jax, numpy as np
+from repro.configs import get_config
+from repro.serving.executor import build_replica_executors
+cfg = get_config("qwen2-1.5b").reduced()
+exs = build_replica_executors(cfg, 5)
+assert [e.device.id for e in exs] == [0, 1, 2, 3, 0], exs
+assert exs[4] is exs[0]
+for e in exs[:4]:
+    leaves = jax.tree.leaves((e.params, e.stacked_ctrl))
+    assert {d for a in leaves for d in a.devices()} == {e.device}
+    assert e.prefill(0, np.ones((1, 16), np.int32)).shape == (1, cfg.vocab_size)
+print("ok")
+"""
+
+
+def test_replica_executors_one_per_device():
+    """Replica r runs on local device r % n, params committed there; a
+    device hosting two replicas holds one executor."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from conftest import cpu_subprocess_env
+    r = subprocess.run(
+        [sys.executable, "-c", REPLICA_DEVICES], capture_output=True,
+        text=True, timeout=300, cwd=Path(__file__).resolve().parents[1],
+        env=cpu_subprocess_env(
+            XLA_FLAGS="--xla_force_host_platform_device_count=4"))
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr[-2000:]

@@ -383,12 +383,32 @@ class TestHostDevicePinning:
         hello = asyncio.run(main())
         assert hello["devices"] == 3
 
-    def test_host_devices_env_flag(self):
-        from repro.compat import host_devices_env
-        env = host_devices_env(4)
+    def test_host_devices_env_flag(self, monkeypatch):
+        from repro.serving.ipc import ReplicaSpec, replica_env
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.delenv("XLA_FLAGS", raising=False)
+        spec = ReplicaSpec(profile={}, policy="maxacc", host_devices=4)
+        env = replica_env(spec)
         assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+        # the child inherits the parent's platform; nothing forces it
         assert env["JAX_PLATFORMS"] == "cpu"
-        assert "XLA_FLAGS" not in host_devices_env(0)
+        spec.host_devices = 0
+        assert "XLA_FLAGS" not in replica_env(spec)
+
+    def test_jax_children_refused_off_cpu(self, monkeypatch):
+        """A child that runs JAX would contend for the parent's chip:
+        spawning one without JAX_PLATFORMS=cpu is refused, loudly."""
+        from repro.serving.ipc import ReplicaSpec, replica_env
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        for spec in (ReplicaSpec(profile={}, policy="maxacc",
+                                 host_devices=2),
+                     ReplicaSpec(profile={}, policy="maxacc",
+                                 execute="real", arch="qwen2-1.5b")):
+            with pytest.raises(RuntimeError, match="JAX_PLATFORMS"):
+                replica_env(spec)
+        # echo children never touch JAX: they inherit whatever is set
+        env = replica_env(ReplicaSpec(profile={}, policy="maxacc"))
+        assert "JAX_PLATFORMS" not in env
 
 
 # --------------------------------------------------------------------------
@@ -517,8 +537,7 @@ class TestRemoteAdopt:
         """A replica_proc started OUT OF BAND (the remote-host path:
         own Popen, --connect + --token on argv) is adopted through the
         listener and serves its round-robin share of a paced trace."""
-        from repro.compat import host_devices_env
-        from repro.serving.ipc import _src_root
+        from repro.serving.ipc import ReplicaSpec, replica_env
 
         async def main():
             router = ClusterRouter(PROF, policies.MaxAcc(), [1],
@@ -529,7 +548,7 @@ class TestRemoteAdopt:
             proc = subprocess.Popen(
                 [sys.executable, "-m", "repro.serving.replica_proc",
                  "--connect", f"{host}:{port}", "--token", router.token],
-                env=host_devices_env(0, PYTHONPATH=_src_root()))
+                env=replica_env(ReplicaSpec(profile={}, policy="maxacc")))
             try:
                 rid = await router.adopt_replica(n_workers=1,
                                                  timeout=30.0)
