@@ -26,6 +26,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
+from repro.serving import spans
 from repro.serving.forecast import ArrivalForecaster, ForecastConfig
 from repro.serving.metrics import summarize
 from repro.serving.policies import Policy
@@ -104,6 +105,7 @@ class Dispatch:
     # filled by SchedulingEngine.launch()
     launched: bool = False
     t_launch: Optional[float] = None
+    seq: int = -1                       # index of its DispatchRecord
     service: Optional[float] = None     # expected service latency (s)
     acc: Optional[float] = None
     # transport-owned actual finish time (may differ from t_launch +
@@ -226,8 +228,13 @@ class SchedulingEngine:
         if not len(self.edf):
             return None
         slack = self.edf.head_slack(now)
-        dec = self.policy.choose(self.profile, slack, len(self.edf),
-                                 residency=self.residency.view(wid))
+        with spans.span("engine.policy", replica=self.replica_id,
+                        queue_len=len(self.edf)) as sp:
+            dec = self.policy.choose(self.profile, slack, len(self.edf),
+                                     residency=self.residency.view(wid))
+            if dec is not None:
+                sp.set(batch_size=int(dec.batch_size),
+                       subnet=int(dec.pareto_idx))
         if dec is None:
             return None
         batch = self.edf.pop_batch(dec.batch_size)
@@ -376,6 +383,7 @@ class SchedulingEngine:
         d.acc = float(self.profile.accs[d.pareto_idx])
         d.open = False
         d.launched = True
+        d.seq = len(self.dispatches)
         self.open_batches.pop(d.wid, None)
         self.dispatches.append(DispatchRecord(now, d.wid, eff_b, d.pareto_idx,
                                               d.acc, lat, len(self.edf),

@@ -63,11 +63,16 @@ from repro.core import subnet as sn
 from repro.core.pareto import ParetoPoint, pareto_subnets
 from repro.kernels.dispatch import model_tier
 from repro.models import lm
+from repro.serving import spans
 
 __all__ = ["ExecutorConfig", "SubnetExecutor", "DecodeCache",
            "bucket_of", "init_params", "build_executor",
            "build_replica_executors", "build_serving_executor",
            "stacked_controls", "prefill_fn", "decode_fn"]
+
+
+# the compiled steps; the ``executor.compile`` span's ``kind`` is an index
+STEP_KINDS = ("prefill", "decode")
 
 
 def bucket_of(n: int, buckets: Sequence[int]) -> int:
@@ -190,18 +195,25 @@ class SubnetExecutor:
         B, S = tokens.shape
         Bb = bucket_of(B, self.xcfg.batch_buckets)
         Sb = bucket_of(S, self.xcfg.seq_buckets)
-        lens = np.full((Bb,), Sb, np.int32)
-        lens[:B] = S if lengths is None else np.asarray(lengths, np.int32)
-        if (Bb, Sb) != (B, S):
-            padded = np.zeros((Bb, Sb), np.int32)
-            padded[:B, :S] = tokens
-            tokens = padded
+        with spans.span("executor.pad", rows=B, bucket_rows=Bb, seq=S,
+                        bucket_seq=Sb):
+            lens = np.full((Bb,), Sb, np.int32)
+            lens[:B] = S if lengths is None else np.asarray(lengths, np.int32)
+            if (Bb, Sb) != (B, S):
+                padded = np.zeros((Bb, Sb), np.int32)
+                padded[:B, :S] = tokens
+                tokens = padded
         fn = self._get("prefill", Bb, Sb)
-        out = fn(self.params, self.stacked_ctrl, tokens,
-                 np.int32(subnet_idx), lens)
+        dev = self.device.id
+        with spans.span("executor.launch", device=dev):
+            out = fn(self.params, self.stacked_ctrl, tokens,
+                     np.int32(subnet_idx), lens)
+        with spans.span("executor.device_wait", device=dev):
+            out.block_until_ready()
         # host copy + host slice: a device-side out[:B] would compile a
         # tiny gather per (bucket, B) pair, breaking zero-compile serving
-        return np.asarray(out)[:B]
+        with spans.span("executor.fetch", device=dev, bytes=out.nbytes):
+            return np.asarray(out)[:B]
 
     def init_cache(self, batch: int, seq_cap: int) -> DecodeCache:
         """Fresh decode cache at the bucketed (batch, capacity)."""
@@ -314,7 +326,9 @@ class SubnetExecutor:
                 self._counters["hits"] += 1
                 return fn
             self._counters["misses"] += 1
-            fn = self._build(kind, Bb, Sb)
+            with spans.span("executor.compile", kind=STEP_KINDS.index(kind),
+                            bucket_rows=Bb, bucket_seq=Sb):
+                fn = self._build(kind, Bb, Sb)
             self._cache[key] = fn
             self._counters["compiles"] += 1
             while len(self._cache) > self.xcfg.max_entries:
@@ -362,8 +376,9 @@ def prefill_fn(cfg: ArchConfig, slice_mode: str = "mask") -> Callable:
     """The executor's prefill step, ``(params, stacked_ctrl, tokens (B,
     S), subnet_idx, lengths (B,)) -> (B, vocab)`` logits at each row's
     last real position; jit it (tests/test_tpu_compile.py compiles it
-    for a described TPU)."""
-    def fn(params, stacked, tokens, idx, lengths):
+    for a described TPU). Its name names the compiled module in a
+    device trace: ``jit_prefill_step``."""
+    def prefill_step(params, stacked, tokens, idx, lengths):
         ctrl = {k: v[idx] for k, v in stacked.items()}
         logits = lm.forward(params, cfg, {"tokens": tokens}, ctrl,
                             slice_mode=slice_mode)
@@ -372,17 +387,18 @@ def prefill_fn(cfg: ArchConfig, slice_mode: str = "mask") -> Callable:
         # tier in tests/test_executor.py)
         pos = jnp.clip(lengths - 1, 0, tokens.shape[1] - 1)
         return jnp.take_along_axis(logits, pos[:, None, None], axis=1)[:, 0]
-    return fn
+    return prefill_step
 
 
 def decode_fn(cfg: ArchConfig, slice_mode: str = "mask") -> Callable:
     """The executor's decode step, ``(params, stacked_ctrl, tokens (B,
-    1), cache, subnet_idx, index) -> (logits, new_cache)``."""
-    def fn(params, stacked, tokens, cache, idx, index):
+    1), cache, subnet_idx, index) -> (logits, new_cache)``; compiled as
+    ``jit_decode_step``."""
+    def decode_step(params, stacked, tokens, cache, idx, index):
         ctrl = {k: v[idx] for k, v in stacked.items()}
         return lm.decode_step(params, cfg, tokens, ctrl, cache, index,
                               slice_mode=slice_mode)
-    return fn
+    return decode_step
 
 
 def init_params(cfg: ArchConfig, seed: int = 0) -> Dict:

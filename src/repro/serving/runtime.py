@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.serving import spans
 from repro.serving.autoscaler import (AutoscaleConfig, ClusterAutoscaler,
                                       coordinator_forecast)
 from repro.serving.cluster import (ClusterCoordinator, drive_cluster,
@@ -239,31 +240,42 @@ class Router:
             return
         if not d.launched:
             self.engine.launch(d, self.clock.now())
+        rid = self.engine.replica_id
+        if spans.enabled() and isinstance(self.clock, WallClock):
+            # the engine's clock may be virtual; this one is perf_counter
+            for q in d.queries:
+                spans.interval("engine.queue_wait", q.arrival, d.t_launch,
+                               qid=q.qid, batch=d.seq, replica=rid)
         # payloads may be gone for queries resolved by an early drain()
         pairs = [(q, self._payloads.get(q.qid)) for q in d.queries]
         payloads = [sq.payload for _, sq in pairs if sq is not None]
-        if payloads:
-            # SubNetAct actuation == a different control tuple; executed
-            # in a thread so the event loop keeps routing.
-            preds = await asyncio.to_thread(worker.run, d.pareto_idx, payloads)
-        else:
-            preds = []
-        fin = self.clock.now()
-        if d.faulted:
-            # worker died mid-batch: the engine already re-enqueued the
-            # queries — discard the (lost) result and wake the scheduler
-            await self._notify()
-            return
-        self.engine.complete(d, fin)
-        arr = np.asarray(preds)
-        i = 0
-        for q, sq in pairs:
-            if sq is None:
-                continue
-            self._payloads.pop(q.qid, None)
-            if not sq.done.done():
-                sq.done.set_result((arr[i], d.acc))
-            i += 1
+        with spans.span("router.batch", awaits=True, replica=rid,
+                        batch=d.seq, rows=len(payloads),
+                        subnet=int(d.pareto_idx)):
+            if payloads:
+                # SubNetAct actuation == a different control tuple;
+                # executed in a thread so the event loop keeps routing.
+                preds = await asyncio.to_thread(_run_worker, worker, d, rid,
+                                                payloads)
+            else:
+                preds = []
+            fin = self.clock.now()
+            if d.faulted:
+                # worker died mid-batch: the engine already re-enqueued
+                # the queries — discard the (lost) result and wake the
+                # scheduler
+                await self._notify()
+                return
+            self.engine.complete(d, fin)
+            arr = np.asarray(preds)
+            i = 0
+            for q, sq in pairs:
+                if sq is None:
+                    continue
+                self._payloads.pop(q.qid, None)
+                if not sq.done.done():
+                    sq.done.set_result((arr[i], d.acc))
+                i += 1
         async with self._work:
             if worker.alive:
                 self._idle.append(worker)
@@ -683,11 +695,20 @@ class ClusterRouter:
         return self.coord.records()
 
 
+def _run_worker(worker: WorkerHandle, d: Dispatch, replica: int,
+                payloads: List[Any]):
+    """A batch's step, in the worker thread."""
+    with spans.span("worker.run", replica=replica, batch=d.seq):
+        return worker.run(d.pareto_idx, payloads)
+
+
 def make_supernet_workers(n: int, step_fn: Callable[[int, Any], Any],
                           pad_batch: Callable[[List[Any]], Any]) -> List[WorkerHandle]:
     """Workers sharing one jitted supernet step. ``step_fn(subnet_idx,
     batch_array)`` must be jit-compiled with the control tuple as data
     so actuation never recompiles."""
     def run(subnet_idx: int, payloads: List[Any]):
-        return step_fn(subnet_idx, pad_batch(payloads))
+        with spans.span("executor.stack", rows=len(payloads)):
+            batch = pad_batch(payloads)
+        return step_fn(subnet_idx, batch)
     return [WorkerHandle(wid=i, run=run) for i in range(n)]

@@ -153,6 +153,20 @@ def test_real_router_serving_is_compile_free(warmed):
     assert cc.count == 0
 
 
+def test_compiled_steps_are_named_after_their_functions(warmed):
+    """The device trace names a program after the jitted function: the
+    executor's steps read ``jit_prefill_step`` and ``jit_decode_step``."""
+    from repro.serving.executor import prefill_fn
+    lowered = jax.jit(prefill_fn(warmed.cfg)).lower(
+        warmed.params, warmed.stacked_ctrl, np.ones((1, 8), np.int32),
+        np.int32(0), np.full((1,), 8, np.int32))
+    assert "@jit_prefill_step" in lowered.as_text()
+    assert "HloModule jit_prefill_step" in \
+        warmed._get("prefill", 1, 8).as_text()
+    assert "HloModule jit_decode_step" in \
+        warmed._get("decode", 1, 8).as_text()
+
+
 # --------------------------------------------------------------------------
 # LRU eviction
 # --------------------------------------------------------------------------
